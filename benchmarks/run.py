@@ -22,6 +22,8 @@ def _parse_row(row: str) -> dict:
 
 def main(argv=None) -> None:
     sys.path.insert(0, "src")
+    from repro.compile_cache import configure_compile_cache
+    print(f"# compile cache: {configure_compile_cache()}", flush=True)
     from benchmarks import (fig3_single_request, fig4_concurrent, fig5_storage,
                             fig6_round_engine, fig7_service, fig8_faults,
                             fig9_durability, fig10_telemetry, fig11_tiering,

@@ -148,6 +148,13 @@ class CodingScheme:
 # Encode / decode on (stacked) parameter matrices
 # ---------------------------------------------------------------------------
 
+# The encode and decode dots run at full f32 precision.  At default precision
+# a TPU runs an f32 dot as one bf16 pass: decoded rounds then differ from the
+# stored parameters by ~1e-2 relative, and the robust decoder reads that
+# residue as corrupted slices.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 def encode(scheme: CodingScheme, shard_params: jnp.ndarray,
            use_kernel: bool = False, out_dtype=None) -> jnp.ndarray:
     """shard_params: (S, P) -> coded slices (C, P). eq. (6).
@@ -160,13 +167,14 @@ def encode(scheme: CodingScheme, shard_params: jnp.ndarray,
     if use_kernel:
         from repro.kernels.coded_matmul.ops import coded_matmul
         return coded_matmul(b, w, out_dtype=out_dtype)
-    out = b @ w
+    out = jnp.matmul(b, w, precision=_EXACT)
     return out.astype(out_dtype) if out_dtype is not None else out
 
 
 @partial(jax.jit, static_argnames=("out_dtype",))
 def _encode_many(b: jnp.ndarray, mats: tuple, out_dtype=None) -> tuple:
-    outs = tuple(b @ m.astype(jnp.float32) for m in mats)
+    outs = tuple(jnp.matmul(b, m.astype(jnp.float32), precision=_EXACT)
+                 for m in mats)
     if out_dtype is not None:
         outs = tuple(o.astype(out_dtype) for o in outs)
     return outs
@@ -216,7 +224,7 @@ def encode_rounds(enc: jnp.ndarray, hist: jnp.ndarray,
         from repro.kernels.coded_matmul.ops import coded_matmul_rounds
         return coded_matmul_rounds(enc, hist, out_dtype=out_dtype)
     out = jnp.einsum("cs,gsp->gcp", enc.astype(jnp.float32),
-                     hist.astype(jnp.float32))
+                     hist.astype(jnp.float32), precision=_EXACT)
     return out.astype(out_dtype) if out_dtype is not None else out
 
 
@@ -246,7 +254,8 @@ def encode_decode(scheme: CodingScheme, shard_params: jnp.ndarray,
                                    jnp.asarray(dec, jnp.float32),
                                    shard_params.astype(jnp.float32))
     composed = jnp.asarray(dec @ enc_np, jnp.float32)      # (S, S) ~ I
-    return composed @ shard_params.astype(jnp.float32)
+    return jnp.matmul(composed, shard_params.astype(jnp.float32),
+                      precision=_EXACT)
 
 
 def decode_erasure(scheme: CodingScheme, slices: jnp.ndarray,
@@ -263,7 +272,7 @@ def decode_erasure(scheme: CodingScheme, slices: jnp.ndarray,
     if use_kernel:
         from repro.kernels.coded_matmul.ops import coded_matmul
         return coded_matmul(dm, sl)
-    return dm @ sl
+    return jnp.matmul(dm, sl, precision=_EXACT)
 
 
 def decode_vandermonde(scheme: CodingScheme, slices: jnp.ndarray) -> jnp.ndarray:

@@ -31,12 +31,8 @@ from dataclasses import asdict
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes  # noqa: F401  registers bfloat16 with numpy's dtype lookup
 import numpy as np
-
-try:  # registers bfloat16 (and friends) with numpy's dtype lookup
-    import ml_dtypes  # noqa: F401
-except ImportError:  # pragma: no cover - baked into the image
-    pass
 
 from repro.core import coding
 from repro.core.sharding import StagePlan

@@ -21,6 +21,10 @@ the (C, block_p) coded intermediate lives only in VMEM/registers, never HBM.
 This is the verification path (encode then immediately re-decode to check a
 round's slices) at one HBM read + one write of P instead of three passes.
 
+Every dot runs at ``Precision.HIGHEST``: Mosaic's default runs an f32 dot
+as one bf16 pass, which leaves ~5e-3 relative error in the slices — far
+above what a Lagrange decode may add (see ``core/coding.py``).
+
 VMEM working set per step (coded_matmul):
   (block_c*S + S*block_p + block_c*block_p) * 4B
   e.g. block_c=128, S=8, block_p=4096: ~2.2 MiB — well inside ~16 MiB/core.
@@ -33,10 +37,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def _kernel(coeff_ref, w_ref, o_ref):
     o_ref[...] = jax.lax.dot(
-        coeff_ref[...], w_ref[...],
+        coeff_ref[...], w_ref[...], precision=_EXACT,
         preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 
@@ -71,7 +77,7 @@ def coded_matmul_kernel(coeff: jnp.ndarray, w: jnp.ndarray, *,
 
 def _rounds_kernel(coeff_ref, w_ref, o_ref):
     o_ref[0] = jax.lax.dot(
-        coeff_ref[...], w_ref[0],
+        coeff_ref[...], w_ref[0], precision=_EXACT,
         preferred_element_type=jnp.float32,
     ).astype(o_ref.dtype)
 
@@ -109,9 +115,9 @@ def coded_matmul_rounds_kernel(coeff: jnp.ndarray, w: jnp.ndarray, *,
 
 
 def _ed_kernel(enc_ref, dec_ref, w_ref, o_ref):
-    coded = jax.lax.dot(enc_ref[...], w_ref[...],
+    coded = jax.lax.dot(enc_ref[...], w_ref[...], precision=_EXACT,
                         preferred_element_type=jnp.float32)      # (C, blk)
-    o_ref[...] = jax.lax.dot(dec_ref[...], coded,
+    o_ref[...] = jax.lax.dot(dec_ref[...], coded, precision=_EXACT,
                              preferred_element_type=jnp.float32)
 
 

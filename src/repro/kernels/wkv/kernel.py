@@ -8,7 +8,8 @@ r/k/v/lw tiles in and the y tile out.
 
 Layout: grid (B*H, S/CHUNK); chunk axis sequential, state (N,N) in VMEM
 scratch; fori_loop over the CHUNK steps (each step: two (N,N) VPU FMAs + a
-row reduction). u rides along as a (1, N) resident operand per head.
+row reduction). u rides along as a (1, 1, N) resident operand per head:
+Mosaic tiles the last two block dims, so they must equal the array's.
 
 VMEM per step: 4 x (CHUNK x N) tiles + (N,N) state + y tile:
 CHUNK=256, N=64 -> ~300 KiB.
@@ -31,7 +32,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, h0_ref, y_ref, hout_ref,
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                   # (N,)
+    u = u_ref[0, 0].astype(jnp.float32)                # (N,)
 
     def step(t, _):
         r_t = r_ref[0, t, :].astype(jnp.float32)       # (N,)
@@ -54,7 +55,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, h0_ref, y_ref, hout_ref,
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv_kernel(r, k, v, lw, u, h0, *, chunk: int = 256,
                interpret: bool = False):
-    """r,k,v,lw: (BH, S, N); u: (BH, N) (head-broadcast by the wrapper);
+    """r,k,v,lw: (BH, S, N); u: (BH, 1, N) (head-broadcast by the wrapper);
     h0: (BH, N, N). S % chunk == 0. Returns (y (BH,S,N) f32, h_last)."""
     bh, s, n = r.shape
     assert s % chunk == 0
@@ -68,7 +69,7 @@ def wkv_kernel(r, k, v, lw, u, h0, *, chunk: int = 256,
             pl.BlockSpec((1, chunk, n), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, n), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, n), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, n, n), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[

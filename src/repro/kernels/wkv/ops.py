@@ -35,7 +35,7 @@ def _wkv_fwd_only(r, k, v, lw, u, h0, chunk):
 
     rf, kf, vf = fold(r), fold(k), fold(v)
     lwf = fold(lw)
-    uf = jnp.broadcast_to(u[None], (b, h, n)).reshape(b * h, n)
+    uf = jnp.broadcast_to(u[None], (b, h, n)).reshape(b * h, 1, n)
     h0f = h0.reshape(b * h, n, n)
     y, h_last = wkv_kernel(rf, kf, vf, lwf, uf, h0f, chunk=chunk,
                            interpret=not on_tpu())
