@@ -58,7 +58,7 @@ def run(sc: Scale):
     emit("fig10_tracer_on", on_us,
          f"spans={spans};overhead_vs_off={(on_us / off_us - 1) * 100:.2f}pct")
 
-    configure(enabled=True, annotate_costs=True)
+    configure(enabled=True)
     export_us = _stage_wall(sc)
     tr = get_tracer()
     trace = to_chrome_trace(tr)

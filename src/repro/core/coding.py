@@ -218,14 +218,16 @@ def encode_rounds(enc: jnp.ndarray, hist: jnp.ndarray,
     axis.  Kernel path: a (G, C_tiles, P_tiles)-grid Pallas matmul that
     streams each round's (S, block_p) tile through the MXU with NO
     concatenate copy (``encode_batched``'s kernel path concatenated the
-    rounds host-visibly first).
+    rounds host-visibly first).  Both paths run under the ``coding.encode``
+    named scope, which names their operations in a device trace.
     """
-    if use_kernel:
-        from repro.kernels.coded_matmul.ops import coded_matmul_rounds
-        return coded_matmul_rounds(enc, hist, out_dtype=out_dtype)
-    out = jnp.einsum("cs,gsp->gcp", enc.astype(jnp.float32),
-                     hist.astype(jnp.float32), precision=_EXACT)
-    return out.astype(out_dtype) if out_dtype is not None else out
+    with jax.named_scope("coding.encode"):
+        if use_kernel:
+            from repro.kernels.coded_matmul.ops import coded_matmul_rounds
+            return coded_matmul_rounds(enc, hist, out_dtype=out_dtype)
+        out = jnp.einsum("cs,gsp->gcp", enc.astype(jnp.float32),
+                         hist.astype(jnp.float32), precision=_EXACT)
+        return out.astype(out_dtype) if out_dtype is not None else out
 
 
 def encode_decode(scheme: CodingScheme, shard_params: jnp.ndarray,

@@ -83,39 +83,48 @@ def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
 
     fl = sim.fl
     g_rounds = rounds or fl.global_rounds
-    with get_tracer().span("stage.train", engine=engine,
-                           store=store_kind) as sp:
-        plan = sim.mgr.new_stage()
-        rng = jax.random.key(sim.seed + plan.stage)
-        w0 = init_params(sim.cfg, rng)
-        dropped = []
-        if faults is not None:
-            by_shard = faults.dropped_clients(plan.stage, plan.shard_clients)
-            for s, cs in by_shard.items():
-                gone = set(cs)
-                plan.shard_clients[s] = [c for c in plan.shard_clients[s]
-                                         if c not in gone]
-                dropped.extend(cs)
-            dropped.sort()
+    tr = get_tracer()
+    with tr.span("stage.train", engine=engine, store=store_kind) as sp:
+        with tr.span("stage.plan") as plan_span:
+            plan = sim.mgr.new_stage()
+            plan_span.annotate(stage=plan.stage)
+            rng = jax.random.key(sim.seed + plan.stage)
+            w0 = init_params(sim.cfg, rng)
+            dropped = []
+            if faults is not None:
+                by_shard = faults.dropped_clients(plan.stage,
+                                                  plan.shard_clients)
+                for s, cs in by_shard.items():
+                    gone = set(cs)
+                    plan.shard_clients[s] = [c for c in plan.shard_clients[s]
+                                             if c not in gone]
+                    dropped.extend(cs)
+                dropped.sort()
+            store = sim._make_store(store_kind, plan,
+                                    group_rounds=encode_group or g_rounds,
+                                    slice_dtype=slice_dtype,
+                                    **(store_options or {}))
+            if faults is not None and hasattr(store, "attach_faults"):
+                store.attach_faults(faults)
         sp.annotate(stage=plan.stage, shards=len(plan.shard_clients),
                     rounds=g_rounds, dropped=len(dropped))
-        store = sim._make_store(store_kind, plan,
-                                group_rounds=encode_group or g_rounds,
-                                slice_dtype=slice_dtype,
-                                **(store_options or {}))
-        if faults is not None and hasattr(store, "attach_faults"):
-            store.attach_faults(faults)
         # the store's preferred payload form decides what the jitted round
         # step computes on device; anything unknown degrades to stacked trees.
         kind = ("flat" if getattr(store, "wants", "stacked") == "flat"
                 else "stacked")
-        data = {s: sim._stack_client_data(cs)
-                for s, cs in plan.shard_clients.items()}
+        with tr.span("stage.data", stage=plan.stage):
+            data = {s: sim._stack_client_data(cs)
+                    for s, cs in plan.shard_clients.items()}
+            stacked = engine == "stage" and _stackable(plan, data)
+            if stacked:
+                shards = sorted(plan.shard_clients)
+                xs = jnp.stack([data[s][0] for s in shards])  # (S, M, n, ...)
+                ys = jnp.stack([data[s][1] for s in shards])
 
+        if stacked:
+            return _run_stage_program(sim, plan, store, w0, xs, ys,
+                                      g_rounds, kind, slice_dtype)
         if engine == "stage":
-            if _stackable(plan, data):
-                return _run_stage_program(sim, plan, store, w0, data,
-                                          g_rounds, kind, slice_dtype)
             sp.annotate(degraded="ragged_stage")
             if faults is not None:
                 from repro.faults.events import DegradedModeEvent
@@ -145,16 +154,21 @@ def _flat_row_len(w0) -> int:
                for l in jax.tree.leaves(w0))
 
 
-def _run_stage_program(sim, plan, store, w0, data, g_rounds, kind,
+def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
                        slice_dtype):
     """The whole-stage superfusion: ONE jitted dispatch runs all G rounds of
-    all S shards and (for the coded store) the Lagrange encode."""
+    all S shards and (for the coded store) the Lagrange encode.
+
+    ``xs``/``ys`` are the stage's data stacked to (S, M, n, ...).  The
+    ``xla.stage_program`` span is the host's dispatch of the program and
+    closes at enqueue; the program's own execution is ``jit_stage_program``
+    in a device trace.  ``stage.collect`` covers the host's reading of the
+    results: the per-shard slices, the one norms transfer and its loop.
+    """
     from repro.fl.simulator import StackedRoundGlobals, StageRecord
 
     fl = sim.fl
     shards = sorted(plan.shard_clients)
-    xs = jnp.stack([data[s][0] for s in shards])      # (S, M, n, ...)
-    ys = jnp.stack([data[s][1] for s in shards])
     # in-program encode only when the store can register pre-encoded slices
     encode = kind == "flat" and hasattr(store, "put_stage_encoded")
     use_kernel = bool(getattr(store, "use_kernel", False))
@@ -169,10 +183,7 @@ def _run_stage_program(sim, plan, store, w0, data, g_rounds, kind,
     else:
         args = (w0, xs, ys)
     with tr.span("xla.stage_program", stage=plan.stage, shards=len(shards),
-                 rounds=g_rounds, encode=encode) as sp:
-        if tr.annotate_costs:
-            from repro.telemetry.export import hlo_cost_of
-            sp.annotate(**hlo_cost_of(prog, *args))
+                 rounds=g_rounds, encode=encode):
         final, round_in, hist, norms_dev = prog(*args)
     if encode:
         store.put_stage_encoded(hist, row_spec,
@@ -190,17 +201,18 @@ def _run_stage_program(sim, plan, store, w0, data, g_rounds, kind,
                      for i, s in enumerate(shards)})
             store.put_round(payload)
     store.flush()
-    shard_models = {s: jax.tree.map(lambda a, i=i: a[i], final)
-                    for i, s in enumerate(shards)}
-    round_globals = {s: StackedRoundGlobals(round_in, final, i)
-                     for i, s in enumerate(shards)}
-    # ONE host sync for every stored-update norm of the stage
-    arr = np.asarray(jax.device_get(norms_dev))        # (G, S, M)
-    norms = {}
-    for i, s in enumerate(shards):
-        for g in range(g_rounds):
-            for j, c in enumerate(plan.shard_clients[s]):
-                norms[(s, g, c)] = float(arr[g, i, j])
+    with tr.span("stage.collect", stage=plan.stage):
+        shard_models = {s: jax.tree.map(lambda a, i=i: a[i], final)
+                        for i, s in enumerate(shards)}
+        round_globals = {s: StackedRoundGlobals(round_in, final, i)
+                         for i, s in enumerate(shards)}
+        # ONE host sync for every stored-update norm of the stage
+        arr = np.asarray(jax.device_get(norms_dev))        # (G, S, M)
+        norms = {}
+        for i, s in enumerate(shards):
+            for g in range(g_rounds):
+                for j, c in enumerate(plan.shard_clients[s]):
+                    norms[(s, g, c)] = float(arr[g, i, j])
     return StageRecord(plan, shard_models, round_globals, store,
                        history_norms=norms)
 

@@ -221,8 +221,9 @@ class FLSimulator:
                                                   (xb, yb))
                 return (params, state), None
 
-            (params, _), _ = jax.lax.scan(epoch_body, (params, state), None,
-                                          length=epochs)
+            with jax.named_scope("fl.local_train"):
+                (params, _), _ = jax.lax.scan(epoch_body, (params, state),
+                                              None, length=epochs)
             return params
 
         def vmapped_train(params, xs, ys, epochs):
@@ -236,13 +237,14 @@ class FLSimulator:
             and (optionally) the stacked (M, P) flat parameter matrix for the
             coded store. Returns (new_global, payload, delta_norms)."""
             locals_ = vmapped_train(params, xs, ys, epochs)
-            deltas = unlearning.stacked_sub(locals_, params)
-            norms = unlearning.stacked_norms(deltas)
-            new_global = unlearning.stacked_mean(locals_)
-            if payload == "flat":
-                out, _ = coding.tree_to_flat_stacked(locals_)
-            else:
-                out = locals_
+            with jax.named_scope("fl.aggregate"):
+                deltas = unlearning.stacked_sub(locals_, params)
+                norms = unlearning.stacked_norms(deltas)
+                new_global = unlearning.stacked_mean(locals_)
+                if payload == "flat":
+                    out, _ = coding.tree_to_flat_stacked(locals_)
+                else:
+                    out = locals_
             return new_global, out, norms
 
         def calib_round(params, xs, ys, stored_norms, epochs):
@@ -327,8 +329,9 @@ class FLSimulator:
                 body, ws0, None, length=g_rounds)
             return final, round_in, hist, norms
 
+        # named so that its module is ``jit_stage_program`` in a device trace
         if encode:
-            def program(w0, xs, ys, enc):
+            def stage_program(w0, xs, ys, enc):
                 final, round_in, hist, norms = stage_body(w0, xs, ys)
                 g, s = hist.shape[:2]
                 coded = coding.encode_rounds(enc, hist.reshape(g, s, -1),
@@ -336,9 +339,9 @@ class FLSimulator:
                                              out_dtype=out_dtype)
                 return final, round_in, coded, norms
         else:
-            def program(w0, xs, ys):
+            def stage_program(w0, xs, ys):
                 return stage_body(w0, xs, ys)
-        prog = jax.jit(program)
+        prog = jax.jit(stage_program)
         self._stage_programs[key] = prog
         return prog
 

@@ -24,7 +24,6 @@ from repro.telemetry.audit import (
     verify_journal,
 )
 from repro.telemetry.export import (
-    hlo_cost_of,
     render_tree,
     to_chrome_trace,
     validate_chrome_trace,
@@ -50,7 +49,6 @@ __all__ = [
     "journal_chain",
     "verify_chain",
     "verify_journal",
-    "hlo_cost_of",
     "render_tree",
     "to_chrome_trace",
     "validate_chrome_trace",

@@ -5,9 +5,7 @@ human summary tree.
   directly (https://ui.perfetto.dev): one complete ("X") event per span and
   one instant ("i") event per tracer event, laid out one lane per
   device/worker — spans labelled ``device=<i>`` land on a ``device-<i>``
-  lane, everything else on its recording thread's lane.  XLA-dispatch spans
-  annotated by the stage engine carry ``roofline.hlo_cost`` FLOP/byte
-  estimates in their ``args``.
+  lane, everything else on its recording thread's lane.
 * ``validate_chrome_trace`` — structural validation against the trace-event
   schema (required keys, phase-specific fields, numeric timestamps); the CI
   telemetry job fails on any finding.
@@ -21,33 +19,6 @@ from __future__ import annotations
 
 import json
 from typing import Dict, List
-
-# FLOP/byte annotations per compiled program: keyed on the jitted callable's
-# id — safe because annotated programs live in the simulator's program cache
-# for the simulator's lifetime.
-_COST_CACHE: Dict[int, dict] = {}
-
-
-def hlo_cost_of(fn, *args) -> dict:
-    """``roofline.hlo_cost`` FLOP/byte estimates for a jitted program, via
-    one cached AOT lower+compile.  Returns ``{}`` when the backend does not
-    expose a cost analysis (never raises — annotation is best-effort)."""
-    key = id(fn)
-    if key in _COST_CACHE:
-        return _COST_CACHE[key]
-    try:
-        from repro.roofline.hlo_cost import xla_cost_analysis
-        ca = xla_cost_analysis(fn.lower(*args).compile())
-        out = {}
-        if "flops" in ca:
-            out["hlo_flops"] = float(ca["flops"])
-        if "bytes accessed" in ca:
-            out["hlo_bytes_accessed"] = float(ca["bytes accessed"])
-    except Exception:                      # noqa: BLE001 — best-effort
-        out = {}
-    _COST_CACHE[key] = out
-    return out
-
 
 # ---------------------------------------------------------------------------
 # Chrome / Perfetto

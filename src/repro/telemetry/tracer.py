@@ -31,6 +31,11 @@ Design points, in the order they matter:
   canonical ``signature()`` hashes names, labels, virtual times, and
   nesting — never wall times or thread names — so two seeded service runs
   produce bit-identical span trees (asserted in tests).
+* **On the profiler's clock.**  A recording tracer also opens a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<span name>`` for each span,
+  on the span's own thread, so under ``jax.profiler`` the spans land in the
+  trace beside the device's operations.  Labels stay out of the annotation's
+  name, which is matched as is.  The null tracer opens none.
 """
 from __future__ import annotations
 
@@ -39,6 +44,8 @@ import json
 import threading
 import time
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.telemetry.metrics import MetricsRegistry, NullMetrics
 
@@ -49,7 +56,7 @@ class Span:
     the enclosing span (or the tracer's root list)."""
 
     __slots__ = ("name", "labels", "kind", "t0", "t1", "v0", "v1", "lane",
-                 "children", "_tracer")
+                 "children", "_tracer", "_note")
 
     def __init__(self, tracer: "Tracer", name: str, labels: dict,
                  kind: str = "span"):
@@ -61,6 +68,7 @@ class Span:
         self.v0 = self.v1 = None          # virtual times (clock attached)
         self.lane = ""
         self.children: List["Span"] = []
+        self._note = None                 # the profiler annotation, when open
 
     # ---------------------------------------------------------------- enter
     def __enter__(self) -> "Span":
@@ -71,10 +79,14 @@ class Span:
         if clock is not None:
             self.v0 = float(clock.now)
         tr._stack().append(self)
+        self._note = TraceAnnotation(f"repro.{self.name}")
+        self._note.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         tr = self._tracer
+        self._note.__exit__(exc_type, exc, tb)
+        self._note = None
         self.t1 = time.perf_counter() - tr.epoch
         clock = tr.clock
         if clock is not None:
@@ -91,7 +103,7 @@ class Span:
 
     def annotate(self, **labels) -> "Span":
         """Attach labels after creation (e.g. recovery counts discovered
-        mid-span, FLOP/byte estimates of the dispatched program)."""
+        mid-span)."""
         self.labels.update(labels)
         return self
 
@@ -150,10 +162,9 @@ class Tracer:
 
     enabled = True
 
-    def __init__(self, clock=None, annotate_costs: bool = False):
+    def __init__(self, clock=None):
         self.epoch = time.perf_counter()
         self.clock = clock                 # optional VirtualClock
-        self.annotate_costs = bool(annotate_costs)
         self.metrics = MetricsRegistry()
         self.roots: List[Span] = []
         self.trace_path: Optional[str] = None
@@ -235,7 +246,6 @@ class NullTracer:
 
     enabled = False
     clock = None
-    annotate_costs = False
     trace_path = None
     metrics = NullMetrics()
     roots: List[Span] = []
@@ -286,18 +296,12 @@ def set_tracer(tracer) -> None:
     _CURRENT = tracer if tracer is not None else NULL_TRACER
 
 
-def configure(enabled: bool = True, clock=None,
-              annotate_costs: bool = False):
+def configure(enabled: bool = True, clock=None):
     """Install (and return) a fresh recording tracer, or restore the no-op
-    default with ``enabled=False``.
-
-    ``annotate_costs=True`` additionally annotates XLA-dispatch spans with
-    ``roofline.hlo_cost`` FLOP/byte estimates (one extra AOT compile per
-    unique program — leave off for overhead-sensitive runs).
-    """
+    default with ``enabled=False``."""
     if not enabled:
         set_tracer(None)
         return NULL_TRACER
-    tracer = Tracer(clock=clock, annotate_costs=annotate_costs)
+    tracer = Tracer(clock=clock)
     set_tracer(tracer)
     return tracer

@@ -3,11 +3,15 @@ nesting/threading/signatures, the metrics registry, the hash-chained audit
 log (tamper detection + journal splice), Chrome-trace export validation,
 and the acceptance anchors — two seeded service runs under the virtual
 clock produce bit-identical span trees AND bit-identical audit-chain
-heads, and a fault-injected read records injection + recovery telemetry.
+heads, and a fault-injected read records injection + recovery telemetry;
+and the device-trace side: recorded spans reach a ``jax.profiler`` trace as
+``repro.*`` annotations (the null tracer's do not), and the stage program
+is ``jit_stage_program`` with its named scopes in the ``op_name`` metadata.
 """
 import dataclasses
 import json
 import os
+import re
 import threading
 import time
 
@@ -391,3 +395,92 @@ class TestIntegration:
             f"null-tracer overhead {overhead * 1e6:.1f}us "
             f"({per_call * 1e9:.0f}ns/call x {n_sites} sites) exceeds 2% "
             f"of stage wall {stage_wall * 1e3:.1f}ms")
+
+
+# ------------------------------------------- profiler trace and device names
+STAGE_SPANS = {"repro.session.stage", "repro.stage.train", "repro.stage.plan",
+               "repro.stage.data", "repro.xla.stage_program",
+               "repro.store.put_stage", "repro.stage.collect"}
+
+
+def _profiled_span_names(fn):
+    """The ``repro.*`` host annotations of a profiler trace of ``fn()``."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            fn()
+        finally:
+            jax.profiler.stop_trace()
+        data = ProfileData.from_file(
+            glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)[0])
+    return [e.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+def _compiled_text(fn, *args):
+    import jax
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+class TestProfilerTrace:
+    @pytest.mark.parametrize("enabled", [True, False])
+    def test_stage_spans_in_profiler_trace(self, enabled):
+        sim = _tiny_sim(seed=0)
+        session = FederatedSession(sim, store_kind="coded", engine="stage")
+        session.run_stage()                                  # compile first
+        configure(enabled=enabled)
+
+        def two_stages():
+            for _ in range(2):
+                session.run_stage()
+        names = _profiled_span_names(two_stages)
+        if enabled:
+            assert set(names) == STAGE_SPANS
+            assert all(names.count(n) == 2 for n in STAGE_SPANS)
+            assert len(get_tracer().all_spans()) == 2 * len(STAGE_SPANS)
+        else:
+            assert names == []
+
+    def test_span_annotation_name_carries_no_labels(self):
+        tr = configure(enabled=True)
+
+        def spans():
+            with tr.span("outer", stage=3):
+                tr.event("mark", hit=True)
+        assert sorted(_profiled_span_names(spans)) == ["repro.mark",
+                                                       "repro.outer"]
+
+    def test_stage_program_module_and_scopes(self):
+        import jax
+        from repro.models import init_params
+        sim = _tiny_sim(seed=0)
+        fl = sim.fl
+        per = fl.clients_per_round // fl.num_shards
+        xs, ys = sim._stack_client_data(list(range(per)))
+        xs = jnp.stack([xs] * fl.num_shards)
+        ys = jnp.stack([ys] * fl.num_shards)
+        w0 = init_params(sim.cfg, jax.random.key(0))
+        enc = jnp.asarray(CodingScheme(fl.num_shards, fl.clients_per_round)
+                          .encode_matrix(), jnp.float32)
+        prog = sim._get_stage_program(fl.local_epochs, "flat",
+                                      fl.global_rounds, encode=True)
+        text = prog.lower(w0, xs, ys, enc).compile().as_text()
+        assert text.startswith("HloModule jit_stage_program")
+        for scope in ("fl.local_train", "fl.aggregate", "coding.encode"):
+            assert re.search(r'op_name="[^"]*' + re.escape(scope), text), scope
+
+    @pytest.mark.parametrize("use_kernel", [False, True])
+    def test_encode_rounds_scope_on_both_paths(self, use_kernel):
+        from repro.core import coding
+        enc = jnp.ones((6, 2), jnp.float32)
+        hist = jnp.ones((3, 2, 256), jnp.float32)
+        text = _compiled_text(
+            lambda e, h: coding.encode_rounds(e, h, use_kernel=use_kernel),
+            enc, hist)
+        assert 'op_name="jit(<lambda>)/coding.encode/' in text
