@@ -79,8 +79,95 @@ def init_embed(fac: ParamFactory, cfg: ModelConfig):
     return p
 
 
+# Largest number of table rows the lookup reads as a one-hot matmul; a larger
+# vocabulary gathers, since the one-hot's size grows with it.
+ONEHOT_MAX_ROWS = 1024
+
+
 def apply_embed(p, tokens, cfg: ModelConfig):
-    return jnp.take(p["table"], tokens, axis=0)
+    """``table[tokens]``.
+
+    With a small real vocabulary (``V' = round_up(vocab_size, 128) <=
+    ONEHOT_MAX_ROWS``) and a float32 table, the lookup is a one-hot matmul
+    on the MXU over the first ``V'`` rows (``onehot_lookup``) and so is its
+    gradient, in place of a gather and a scatter-add that the TPU runs row
+    by row.  Valid tokens (``< vocab_size``) read exactly what ``jnp.take``
+    reads; rows from ``V'`` on get an exactly zero gradient, as valid tokens
+    never index them.  Otherwise the table is gathered.
+    """
+    table = p["table"]
+    rows = -(-cfg.vocab_size // 128) * 128
+    if rows <= ONEHOT_MAX_ROWS and table.dtype == jnp.float32:
+        with jax.named_scope("embed.onehot"):
+            return onehot_lookup(table[:rows], tokens)
+    with jax.named_scope("embed.gather"):
+        return jnp.take(table, tokens, axis=0)
+
+
+def _bf16_split(x):
+    """``[hi, mid, lo]``, bfloat16, whose float32 sum ``(hi + mid) + lo`` is
+    the float32 ``x`` exactly.  ``hi`` keeps the top 8 bits of ``x``'s
+    24-bit significand (sign, exponent and 7 stored bits, by masking); the
+    rest ``x - hi`` is exact in float32, and ``mid`` keeps its top 8
+    significant bits the same way; what is left spans at most 8 bits and is
+    ``lo``, exact in bfloat16.  Both sums are truncations of ``x``, so exact.
+    (Masking and not rounding leaves no conversion pair a compiler could
+    fold away.)  Entries below about 2**-100 in magnitude, whose ``lo``
+    would fall under bfloat16's normal range, are the exception."""
+    def head(v):
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                            jnp.float32)
+
+    hi = head(x)
+    rest = x - hi
+    mid = head(rest)
+    return [part.astype(jnp.bfloat16) for part in (hi, mid, rest - mid)]
+
+
+def _onehot_dot(onehot, x, contract):
+    """``onehot`` contracted over ``contract`` (its axes, then ``x``'s) with
+    ``x``'s three-way bfloat16 split side by side, accumulated in float32,
+    and the three column groups summed back."""
+    out = jax.lax.dot_general(onehot, jnp.concatenate(_bf16_split(x), -1),
+                              (contract, ((), ())),
+                              preferred_element_type=jnp.float32)
+    hi, mid, lo = jnp.split(out, 3, axis=-1)
+    return (hi + mid) + lo
+
+
+@jax.custom_vjp
+def onehot_lookup(table, tokens):
+    """``table[tokens]`` for a float32 ``table`` of ``rows`` rows and tokens
+    in ``[0, rows)`` (a token outside reads zeros): one bfloat16 matmul of
+    ``one_hot(tokens, rows)`` against ``[hi | mid | lo]``, the table's exact
+    three-way split (``_bf16_split``), accumulated in float32, then the
+    three column groups summed.  The one-hot is exact in bfloat16, a product
+    of bfloat16s is exact in float32, and each output element receives one
+    nonzero product, so each group is its part exactly and their sum is the
+    table's row bit for bit.  One matmul three parts wide, where
+    ``precision=HIGHEST`` would take six passes.
+
+    The VJP is the transposed matmul: ``d_table = one_hot^T @ [dx_hi |
+    dx_mid | dx_lo]``, accumulated in float32 and summed over the groups.
+    Each product is again exact, so this is the scatter-add's sum of each
+    token's cotangent row in another order: equal to float32 round-off.
+    (Plain autodiff of the forward would round the cotangent to bfloat16.)
+    ``tokens`` gets no gradient."""
+    return _onehot_lookup_fwd(table, tokens)[0]
+
+
+def _onehot_lookup_fwd(table, tokens):
+    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=jnp.bfloat16)
+    return _onehot_dot(onehot, table, ((onehot.ndim - 1,), (0,))), onehot
+
+
+def _onehot_lookup_bwd(onehot, d_out):
+    lead = tuple(range(onehot.ndim - 1))
+    return _onehot_dot(onehot, d_out, (lead, lead)), None
+
+
+onehot_lookup.defvjp(_onehot_lookup_fwd, _onehot_lookup_bwd)
 
 
 def apply_unembed(p, x, cfg: ModelConfig):

@@ -6,7 +6,9 @@ a kernel may use.  These tests lower each kernel with ``interpret=False`` at
 the shapes the paper-scale scenario (``ScenarioConfig.paper_full``) feeds it
 and compile it for one chip of a ``v5e:2x2`` topology that is described, not
 attached.  Nothing runs; a kernel passes when the compiler accepts it and
-the executable holds the kernel as a ``tpu_custom_call``.
+the executable holds the kernel as a ``tpu_custom_call``.  One more test
+compiles the nanogpt-paper stage program, small in rounds and epochs, and
+reads in its HLO how the token embedding was lowered.
 
 The topology is described inside a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU library.
@@ -97,3 +99,30 @@ def test_wkv(one_chip):
         x, x, x, x, _sds((bh, 1, n), one_chip), _sds((bh, n, n), one_chip),
         chunk=SEQ, interpret=False).compile()
     _assert_kernel(compiled)
+
+
+def test_nanogpt_stage_program_reads_embedding_by_matmul(one_chip):
+    # The nanogpt-paper stage program at G=1 and one epoch: its token
+    # embedding is a one-hot matmul with a matmul gradient, so the compiled
+    # program holds no gather, no scatter-add and no custom fusion of either.
+    from repro.configs import FLConfig, OptimizerConfig, get_config
+    from repro.fl.simulator import FLSimulator
+    from repro.models import init_params
+    model = get_config("nanogpt-paper")
+    shards, clients, samples = 4, 20, 100
+    fl = FLConfig(num_clients=clients, clients_per_round=clients,
+                  num_shards=shards, local_epochs=1, global_rounds=1)
+    sim = FLSimulator(model, fl, {}, task="generation",
+                      opt_cfg=OptimizerConfig(name="sgd", lr=0.3,
+                                              grad_clip=0.0),
+                      local_batch=BATCH)
+    w0 = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype),
+                      jax.eval_shape(lambda: init_params(model,
+                                                         jax.random.key(0))))
+    data = _sds((shards, clients // shards, samples, SEQ), one_chip, jnp.int32)
+    enc = _sds((clients, shards), one_chip)
+    prog = sim._get_stage_program(1, "flat", 1, encode=True)
+    text = prog.lower(w0, data, data, enc).compile().as_text()
+    assert "embed.onehot" in text
+    for banned in (" gather(", " scatter(", "kind=kCustom", "jit(_take)"):
+        assert banned not in text, banned
