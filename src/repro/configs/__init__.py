@@ -30,9 +30,9 @@ _ARCH_MODULES = {
     # the paper's own models
     "nanogpt-paper": "nanogpt_paper",
     "cnn-paper": "cnn_paper",
+    # federated fine-tuning configurations of the benchmark
+    "moonlight-16b-a3b-fedlora": "moonlight_16b_a3b_fedlora",
 }
-
-ASSIGNED_ARCHS = tuple(k for k in _ARCH_MODULES if not k.endswith("-paper"))
 
 
 def get_config(arch: str) -> ModelConfig:
@@ -40,6 +40,12 @@ def get_config(arch: str) -> ModelConfig:
         raise KeyError(f"unknown arch {arch!r}; known: {sorted(_ARCH_MODULES)}")
     mod = importlib.import_module(f"repro.configs.{_ARCH_MODULES[arch]}")
     return mod.CONFIG
+
+
+# the architectures every dry-run and smoke test covers (train, prefill and
+# decode); a model whose layers have no decode path is trained only
+ASSIGNED_ARCHS = tuple(k for k in _ARCH_MODULES
+                       if not k.endswith("-paper") and get_config(k).serves)
 
 
 def list_archs() -> tuple:

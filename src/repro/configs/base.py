@@ -48,6 +48,30 @@ class ModelConfig:
     moe_impl: str = "einsum"   # einsum (one-hot dispatch) | gather (index-based)
     moe_capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # DeepSeek-V3 gate (arXiv:2412.19437 Sec 2.1.2): "softmax" is top-k of a
+    # softmax, renormalised, with the aux loss; "sigmoid" is noaux_tc with
+    # one group: top-k of sigmoid scores plus a per-expert correction bias
+    # (selection only), the chosen scores normalised and scaled by
+    # ``moe_routed_scale``, every token kept (no capacity), no aux loss.
+    moe_gate: str = "softmax"
+    moe_routed_scale: float = 1.0
+    moe_shared_d_ff: int = 0   # shared experts as one SwiGLU of this width
+    # experts held here under expert parallelism: [0, experts_held) of the
+    # router's num_experts (0 = all); only their part of the layer is computed
+    experts_held: int = 0
+    first_dense_layers: int = 0  # leading layers with a dense d_ff FFN
+
+    # --- multi-head latent attention (DeepSeek-V2; layer kind "mla") ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0  # one RoPE key shared by the heads
+    v_head_dim: int = 0
+
+    # --- LoRA adapters (arXiv:2106.09685) over a frozen base ---
+    # clients train only rank-``lora_rank`` adapters on the four MLA
+    # projections (scaled by lora_alpha / lora_rank); 0 = full-model training
+    lora_rank: int = 0
+    lora_alpha: float = 0.0
 
     # --- attention pattern ---
     # Repeating pattern of layer kinds; entries in {"global","local","mamba","rwkv"}.
@@ -69,6 +93,7 @@ class ModelConfig:
 
     # --- norm / misc ---
     norm_type: str = "rmsnorm"     # rmsnorm | layernorm | nonparametric
+    norm_eps: float = 1e-6
     act: str = "silu"              # silu | gelu
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
@@ -100,10 +125,12 @@ class ModelConfig:
     # -- derived ----------------------------------------------------------
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """Expand layer_pattern to num_layers entries."""
+        """Expand layer_pattern to the stack's entries (``num_layers`` less
+        the leading dense layers)."""
         pat = self.layer_pattern
-        reps = (self.num_layers + len(pat) - 1) // len(pat)
-        return tuple((pat * reps)[: self.num_layers])
+        n = self.layers_in_stack
+        reps = (n + len(pat) - 1) // len(pat)
+        return tuple((pat * reps)[:n])
 
     def param_count(self) -> int:
         """Analytic parameter count (matches init within rounding)."""
@@ -112,8 +139,12 @@ class ModelConfig:
         if not self.tie_embeddings and self.family != "cnn":
             n += self.vocab_size * d                 # unembed
         kinds = self.layer_kinds
+        n += self.first_dense_layers * (self._mla_params() + 3 * d * self.d_ff
+                                        + 2 * self._norm_params())
         for i, kind in enumerate(kinds):
-            if kind in ("global", "local"):
+            if kind == "mla":
+                n += self._mla_params() + self._ffn_params(i) + 2 * self._norm_params()
+            elif kind in ("global", "local"):
                 n += d * (h * hd) + 2 * d * (kv * hd) + (h * hd) * d  # q,k,v,o
                 n += self._ffn_params(i)
                 n += 2 * self._norm_params()
@@ -145,11 +176,43 @@ class ModelConfig:
     def ffn_is_moe(self, layer_idx: int) -> bool:
         return bool(self.num_experts) and (layer_idx % self.moe_every == self.moe_every - 1)
 
+    @property
+    def serves(self) -> bool:
+        """Whether every layer kind has a prefill and decode path (MLA has
+        its training form only)."""
+        return "mla" not in self.layer_pattern
+
+    @property
+    def layers_in_stack(self) -> int:
+        """Layers after the leading dense ones (the pattern's layers)."""
+        return self.num_layers - self.first_dense_layers
+
+    def _mla_params(self) -> int:
+        d, h, r = self.d_model, self.num_heads, self.kv_lora_rank
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        return (d * h * qk + d * (r + self.qk_rope_head_dim) + r
+                + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                + h * self.v_head_dim * d)
+
     def _ffn_params(self, layer_idx: int = 0) -> int:
         if self.ffn_is_moe(layer_idx):
             e, f = self.num_experts, self.moe_d_ff
-            return self.d_model * e + e * (3 * self.d_model * f)  # router + gated mlp
+            n = self.d_model * e + (self.experts_held or e) * (3 * self.d_model * f)
+            if self.moe_gate == "sigmoid":
+                n += e                                       # correction bias
+            return n + 3 * self.d_model * self.moe_shared_d_ff
         return 3 * self.d_model * self.d_ff  # gated mlp (gate,up,down)
+
+    def lora_params(self) -> int:
+        """Adapter parameters: rank x (in + out) of each of the four MLA
+        projections, per layer."""
+        d, h, r = self.d_model, self.num_heads, self.kv_lora_rank
+        nope, rope, v = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+        sizes = ((d, h * (nope + rope)),       # q_proj
+                 (d, r + rope),                # kv_a_proj_with_mqa
+                 (r, h * (nope + v)),          # kv_b_proj
+                 (h * v, d))                   # o_proj
+        return self.lora_rank * sum(map(sum, sizes)) * self.num_layers
 
     def _norm_params(self) -> int:
         return 0 if self.norm_type == "nonparametric" else self.d_model

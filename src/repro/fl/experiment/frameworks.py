@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import unlearning
-from repro.models import init_params
 from repro.telemetry import get_tracer
 
 
@@ -117,15 +116,15 @@ class UnlearnContext:
         return self.sim._stacked_mean(stacked)
 
     def init_model(self, salt: int = 777):
-        return init_params(self.sim.cfg, jax.random.key(self.sim.seed + salt))
+        return self.sim.init_params(jax.random.key(self.sim.seed + salt))
 
     def stage_init_model(self):
         """The stage's ACTUAL initial model w0 (seeded by ``plan.stage``,
         exactly as ``train_stage`` built it) — retraining from it with a
         client removed is the bit-exact counterfactual the retrain oracle
         (``repro.verify.oracle``) measures against."""
-        return init_params(self.sim.cfg,
-                           jax.random.key(self.sim.seed + self.plan.stage))
+        return self.sim.init_params(
+            jax.random.key(self.sim.seed + self.plan.stage))
 
     def retrain_shards(self, w0, xs, ys, g_rounds: int):
         """From-scratch FedAvg of a stacked ``(K, M, n, ...)`` batch of

@@ -35,7 +35,6 @@ import numpy as np
 
 from repro.stores.store import RoundPayload
 from repro.core import coding, unlearning
-from repro.models import init_params
 from repro.telemetry import get_tracer
 
 ENGINES = ("stage", "fused", "legacy")
@@ -89,7 +88,7 @@ def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
             plan = sim.mgr.new_stage()
             plan_span.annotate(stage=plan.stage)
             rng = jax.random.key(sim.seed + plan.stage)
-            w0 = init_params(sim.cfg, rng)
+            w0 = sim.init_params(rng)
             dropped = []
             if faults is not None:
                 by_shard = faults.dropped_clients(plan.stage,
@@ -163,7 +162,11 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
     ``xla.stage_program`` span is the host's dispatch of the program and
     closes at enqueue; the program's own execution is ``jit_stage_program``
     in a device trace.  ``stage.collect`` covers the host's reading of the
-    results: the per-shard slices, the one norms transfer and its loop.
+    results: the per-shard slices, the one norms transfer and its loop.  A
+    model with held experts also leaves the stage's tokens routed to each
+    held expert, summed over rounds and clients, in ``record.expert_load``
+    (MoE layers, held), and its mean and max over experts in the gauges
+    ``moe.expert_tokens{stat=mean|max}`` while a recording tracer is on.
     """
     from repro.fl.simulator import StackedRoundGlobals, StageRecord
 
@@ -184,7 +187,7 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
         args = (w0, xs, ys)
     with tr.span("xla.stage_program", stage=plan.stage, shards=len(shards),
                  rounds=g_rounds, encode=encode):
-        final, round_in, hist, norms_dev = prog(*args)
+        final, round_in, hist, norms_dev, *load = prog(*args)
     if encode:
         store.put_stage_encoded(hist, row_spec,
                                 row_len=_flat_row_len(w0))
@@ -213,8 +216,15 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
             for g in range(g_rounds):
                 for j, c in enumerate(plan.shard_clients[s]):
                     norms[(s, g, c)] = float(arr[g, i, j])
+        expert_load = load[0].sum(axis=(0, 1, 2)) if load else None
+        if expert_load is not None and tr.enabled:
+            per_expert = np.asarray(jax.device_get(expert_load))
+            tr.metrics.gauge("moe.expert_tokens", stat="mean").set(
+                float(per_expert.mean()))
+            tr.metrics.gauge("moe.expert_tokens", stat="max").set(
+                float(per_expert.max()))
     return StageRecord(plan, shard_models, round_globals, store,
-                       history_norms=norms)
+                       history_norms=norms, expert_load=expert_load)
 
 
 def _run_fused(sim, plan, store, w0, data, g_rounds, kind):
@@ -272,7 +282,7 @@ def _train_stage_legacy(sim, store_kind: str = "coded",
     g_rounds = rounds or fl.global_rounds
     plan = sim.mgr.new_stage()
     rng = jax.random.key(sim.seed + plan.stage)
-    w0 = init_params(sim.cfg, rng)
+    w0 = sim.init_params(rng)
     store = sim._make_store(store_kind, plan)
     ws = {s: w0 for s in plan.shard_clients}
     data = {s: sim._stack_client_data(cs)

@@ -149,3 +149,25 @@ class MoEFamily(ModelFamily):
                            experts_per_token=2, moe_d_ff=32,
                            norm_type="rmsnorm", act="silu",
                            source="scenario zoo (moe)", **_TINY_LM)
+
+
+@register_model_family("moonlight")
+class MoonlightLoRAFamily(ModelFamily):
+    """Moonlight-16B-A3B's block (MLA, a leading dense layer, a DeepSeek-V3
+    gate over 8 experts of which 4 are held, one shared expert) at a CPU
+    size, federating rank-4 LoRA adapters on the attention projections over
+    a frozen base: the tiny variant of ``moonlight-16b-a3b-fedlora``."""
+
+    task = "generation"
+    default_lr = 0.1
+
+    def build(self, cfg) -> ModelConfig:
+        return dataclasses.replace(
+            get_config("moonlight-16b-a3b-fedlora"),
+            name="moonlight-fl", num_layers=2, d_model=64, num_heads=4,
+            num_kv_heads=4, head_dim=16, kv_lora_rank=16, qk_nope_head_dim=8,
+            qk_rope_head_dim=8, v_head_dim=8, d_ff=96, moe_d_ff=32,
+            moe_shared_d_ff=32, num_experts=8, experts_per_token=3,
+            experts_held=4, vocab_size=109, lora_rank=4, lora_alpha=8.0,
+            param_dtype="float32", compute_dtype="float32",
+            source="scenario zoo (moonlight, tiny)")

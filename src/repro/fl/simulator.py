@@ -49,6 +49,17 @@ Three selectable engines cover the dispatch-count spectrum
   (``benchmarks/fig6_round_engine.py``) and equivalence tests
   (``tests/test_round_engine.py``).
 
+Adapters over a frozen base
+---------------------------
+A model configured with LoRA adapters (``ModelConfig.lora_rank``) federates
+its adapters: the trainable tree, the stored client models, the coded slices
+and the SE calibration are the adapter tree, and the frozen base, drawn once
+from the seed, is ``sim.base``.  Every jitted program takes the base as an
+argument (``_jit``): broadcast to every client, never vmapped and never one
+of the program's constants, so the clients' tokens fold into the rows of the
+base's matmuls.  Without adapters ``sim.base`` is None and every program is
+the one it always was.
+
 SE/FE calibrated retraining (eq. 3) runs through ``calib_round`` — vmapped
 retraining plus ``unlearning.calibrate_stacked`` fused in one jit — and, when
 several shards retrain together (batched unlearning requests), through the
@@ -57,6 +68,7 @@ calibration rounds scanned, one dispatch for the whole retraining pass.
 """
 from __future__ import annotations
 
+import functools
 import json
 import warnings
 from dataclasses import dataclass, field
@@ -71,7 +83,8 @@ from repro.configs.base import FLConfig, ModelConfig, OptimizerConfig
 from repro.core import coding, unlearning
 from repro.core.sharding import ShardManager, StagePlan
 from repro.fl.tasks import resolve_task
-from repro.models import loss_fn, predict_fn
+from repro.models import (init_adapters, init_params, loss_fn, merge_adapters,
+                          predict_fn)
 from repro.optim import make_optimizer
 from repro.optim.fisher import diag_fisher, fisher_precondition
 
@@ -116,6 +129,9 @@ class StageRecord:
     store: object                                 # parameter store
     history_norms: Dict[Tuple[int, int, int], float] = field(default_factory=dict)
     # (shard, round, client) -> ||delta|| of the stored update
+    # tokens the stage routed to each held expert, (MoE layers, held), on
+    # device; None for a model without held experts or off the stage engine
+    expert_load: Optional[object] = None
 
 
 @dataclass
@@ -187,17 +203,41 @@ class FLSimulator:
         self.seed = seed
         self.mgr = ShardManager(fl_cfg.num_clients, fl_cfg.num_shards,
                                 fl_cfg.clients_per_round, seed)
+        self.base = (init_params(model_cfg, jax.random.key(seed))
+                     if model_cfg.lora_rank else None)
         self._lf = loss_fn(model_cfg)
-        self._pf = predict_fn(model_cfg)
+        self._predict = predict_fn(model_cfg)
+        self._pf = lambda p, b: self._predict(merge_adapters(self.base, p), b)
         self._build_steps()
+
+    def init_params(self, rng):
+        """A stage's initial trainable tree: the model's parameters, or the
+        adapters where the model has them (the base stays ``self.base``)."""
+        if self.base is None:
+            return init_params(self.cfg, rng)
+        return init_adapters(self.cfg, rng)
+
+    def _jit(self, fn):
+        """``fn(base, *args)`` jitted and called as ``prog(*args)``: the
+        frozen base is the program's first argument.  Without a base the
+        program is ``fn(None, *args)`` under ``fn``'s own name."""
+        if self.base is None:
+            prog = functools.partial(fn, None)
+            prog.__name__ = fn.__name__
+            return jax.jit(prog)
+        return functools.partial(jax.jit(fn), self.base)
 
     # ------------------------------------------------------------------ jit
     def _build_steps(self):
-        lf = self._lf
         opt_init, opt_update = make_optimizer(self.opt)
 
-        def local_train(params, xs, ys, epochs, fisher=None):
-            """Minibatch-SGD local training. xs: (n, ...), ys: (n, ...)."""
+        def lf(base, params, batch):
+            return self._lf(merge_adapters(base, params), batch)
+
+        def local_train(base, params, xs, ys, epochs, fisher=None):
+            """Minibatch-SGD local training. xs: (n, ...), ys: (n, ...).
+            Returns (params, load): ``load`` sums the steps' tokens routed
+            to each held expert (MoE layers, held), None without them."""
             bs = self.local_batch
             n = xs.shape[0] // bs * bs
             xb = xs[:n].reshape(-1, bs, *xs.shape[1:])
@@ -211,32 +251,34 @@ class FLSimulator:
                     params, state = carry
                     x, y = xy
                     batch = self._make_batch(x, y)
-                    grads = jax.grad(lambda p: lf(p, batch)[0])(params)
+                    grads, metrics = jax.grad(lambda p: lf(base, p, batch),
+                                              has_aux=True)(params)
                     if fisher is not None:
                         grads = fisher_precondition(grads, fisher)
                     params, state = opt_update(params, grads, state)
-                    return (params, state), None
+                    return (params, state), metrics.get("expert_load")
 
-                (params, state), _ = jax.lax.scan(batch_body, (params, state),
-                                                  (xb, yb))
-                return (params, state), None
+                (params, state), load = jax.lax.scan(
+                    batch_body, (params, state), (xb, yb))
+                return (params, state), load
 
             with jax.named_scope("fl.local_train"):
-                (params, _), _ = jax.lax.scan(epoch_body, (params, state),
-                                              None, length=epochs)
-            return params
+                (params, _), load = jax.lax.scan(epoch_body, (params, state),
+                                                 None, length=epochs)
+            return params, None if load is None else load.sum(axis=(0, 1))
 
-        def vmapped_train(params, xs, ys, epochs):
-            """Stacked data (M, n, ...), shared initial params -> (M, ...)."""
-            return jax.vmap(lambda x, y: local_train(params, x, y, epochs)
-                            )(xs, ys)
+        def vmapped_train(base, params, xs, ys, epochs, fisher=None):
+            """Stacked data (M, n, ...), shared initial params -> (M, ...),
+            and each client's expert load (M, ...) or None."""
+            return jax.vmap(lambda x, y: local_train(base, params, x, y,
+                                                     epochs, fisher))(xs, ys)
 
-        def shard_round(params, xs, ys, epochs, payload):
+        def shard_round(base, params, xs, ys, epochs, payload):
             """One fused FedAvg round for one shard — everything on device:
             vmapped local training, stacked (M,) update norms, FedAvg mean,
             and (optionally) the stacked (M, P) flat parameter matrix for the
-            coded store. Returns (new_global, payload, delta_norms)."""
-            locals_ = vmapped_train(params, xs, ys, epochs)
+            coded store. Returns (new_global, payload, delta_norms, load)."""
+            locals_, load = vmapped_train(base, params, xs, ys, epochs)
             with jax.named_scope("fl.aggregate"):
                 deltas = unlearning.stacked_sub(locals_, params)
                 norms = unlearning.stacked_norms(deltas)
@@ -245,23 +287,24 @@ class FLSimulator:
                     out, _ = coding.tree_to_flat_stacked(locals_)
                 else:
                     out = locals_
-            return new_global, out, norms
+            return new_global, out, norms, load
 
-        def calib_round(params, xs, ys, stored_norms, epochs):
+        def calib_round(base, params, xs, ys, stored_norms, epochs):
             """One fused SE/FE calibrated-retraining round (eq. 3): vmapped
             retraining + stacked calibration, no per-client host loop."""
-            locals_ = vmapped_train(params, xs, ys, epochs)
+            locals_, _ = vmapped_train(base, params, xs, ys, epochs)
             deltas = unlearning.stacked_sub(locals_, params)
             return unlearning.calibrate_stacked(params, deltas, stored_norms)
 
-        def calib_stage(ws, xs, ys, nmats, epochs):
+        def calib_stage(base, ws, xs, ys, nmats, epochs):
             """The whole calibrated-retraining pass of a batch of impacted
             shards in ONE program: ``calib_round`` vmapped over the K shards,
             ``lax.scan``-ed over the G' rounds.  ws: stacked (K, ...) initial
             models; xs/ys: (K, M', n, ...); nmats: (G', K, M') stored norms."""
             def body(w, nrow):
                 w2 = jax.vmap(lambda wi, x, y, n:
-                              calib_round(wi, x, y, n, epochs))(w, xs, ys, nrow)
+                              calib_round(base, wi, x, y, n, epochs)
+                              )(w, xs, ys, nrow)
                 return w2, None
             out, _ = jax.lax.scan(body, ws, nmats)
             return out
@@ -273,25 +316,24 @@ class FLSimulator:
         self._calib_stage = {}
         for ep in set([self.fl.local_epochs,
                        max(int(self.fl.local_epochs / self.fl.retrain_ratio), 1)]):
-            self._local_train[ep] = jax.jit(
-                jax.vmap(lambda p, x, y, e=ep: local_train(p, x, y, e),
-                         in_axes=(None, 0, 0)))
-            self._local_train[(ep, "fisher")] = jax.jit(
-                jax.vmap(lambda p, x, y, f, e=ep: local_train(p, x, y, e, f),
-                         in_axes=(None, 0, 0, None)))
+            self._local_train[ep] = self._jit(
+                lambda b, p, x, y, e=ep: vmapped_train(b, p, x, y, e)[0])
+            self._local_train[(ep, "fisher")] = self._jit(
+                lambda b, p, x, y, f, e=ep: vmapped_train(b, p, x, y, e, f)[0])
             for payload in ("flat", "stacked"):
-                self._shard_round[(ep, payload)] = jax.jit(
-                    lambda p, x, y, e=ep, pay=payload:
-                    shard_round(p, x, y, e, pay))
-            self._calib_round[ep] = jax.jit(
-                lambda p, x, y, n, e=ep: calib_round(p, x, y, n, e))
-            self._calib_stage[ep] = jax.jit(
-                lambda w, x, y, n, e=ep: calib_stage(w, x, y, n, e))
+                self._shard_round[(ep, payload)] = self._jit(
+                    lambda b, p, x, y, e=ep, pay=payload:
+                    shard_round(b, p, x, y, e, pay)[:3])
+            self._calib_round[ep] = self._jit(
+                lambda b, p, x, y, n, e=ep: calib_round(b, p, x, y, n, e))
+            self._calib_stage[ep] = self._jit(
+                lambda b, w, x, y, n, e=ep: calib_stage(b, w, x, y, n, e))
         self._stacked_mean = jax.jit(unlearning.stacked_mean)
-        self._grad_fn = jax.jit(jax.grad(lambda p, b: lf(p, b)[0]))
+        self._grad_fn = self._jit(
+            lambda b, p, batch: jax.grad(lambda q: lf(b, q, batch)[0])(p))
         self._shard_round_fn = shard_round      # unjitted: stage-program body
         self._stage_programs = {}               # (ep, kind, G, enc?, ...) -> jit
-        self._eval_stats = jax.jit(self._eval_stats_fn)
+        self._eval_stats = self._jit(self._eval_stats_fn)
 
     def _get_stage_program(self, epochs: int, kind: str, g_rounds: int,
                            encode: bool, out_dtype=None,
@@ -305,7 +347,10 @@ class FLSimulator:
         ``(final (S, ...), round_inputs (G, S, ...), history, norms (G, S, M))``
         where ``history`` is the coded ``(G, C, M*P)`` slices (``encode``),
         the flat ``(G, S, M, P)`` matrices (``kind == "flat"``), or the
-        stacked per-round trees (``kind == "stacked"``).
+        stacked per-round trees (``kind == "stacked"``); a model with held
+        experts adds ``load (G, S, M, MoE layers, held)``, the tokens each
+        client's training routed to each held expert.  With adapters the
+        program is ``functools.partial(jitted, base)`` (see ``_jit``).
         """
         key = (epochs, kind, g_rounds, encode, out_dtype, use_kernel)
         prog = self._stage_programs.get(key)
@@ -313,35 +358,36 @@ class FLSimulator:
             return prog
         shard_round = self._shard_round_fn
 
-        def stage_body(w0, xs, ys):
+        def stage_body(base, w0, xs, ys):
             s = xs.shape[0]
             ws0 = jax.tree.map(
                 lambda a: jnp.broadcast_to(a.astype(jnp.float32),
                                            (s,) + a.shape), w0)
 
             def body(ws, _):
-                new_ws, out, norms = jax.vmap(
-                    lambda p, x, y: shard_round(p, x, y, epochs, kind)
+                new_ws, out, norms, load = jax.vmap(
+                    lambda p, x, y: shard_round(base, p, x, y, epochs, kind)
                 )(ws, xs, ys)
-                return new_ws, (ws, out, norms)
+                return new_ws, (ws, out, norms, load)
 
-            final, (round_in, hist, norms) = jax.lax.scan(
+            final, (round_in, hist, norms, load) = jax.lax.scan(
                 body, ws0, None, length=g_rounds)
-            return final, round_in, hist, norms
+            return (final, round_in, hist, norms) + (
+                () if load is None else (load,))
 
         # named so that its module is ``jit_stage_program`` in a device trace
         if encode:
-            def stage_program(w0, xs, ys, enc):
-                final, round_in, hist, norms = stage_body(w0, xs, ys)
+            def stage_program(base, w0, xs, ys, enc):
+                final, round_in, hist, *rest = stage_body(base, w0, xs, ys)
                 g, s = hist.shape[:2]
                 coded = coding.encode_rounds(enc, hist.reshape(g, s, -1),
                                              use_kernel=use_kernel,
                                              out_dtype=out_dtype)
-                return final, round_in, coded, norms
+                return (final, round_in, coded, *rest)
         else:
-            def stage_program(w0, xs, ys):
-                return stage_body(w0, xs, ys)
-        prog = jax.jit(stage_program)
+            def stage_program(base, w0, xs, ys):
+                return stage_body(base, w0, xs, ys)
+        prog = self._jit(stage_program)
         self._stage_programs[key] = prog
         return prog
 
@@ -359,22 +405,22 @@ class FLSimulator:
             return prog
         shard_round = self._shard_round_fn
 
-        def program(w0, xs, ys):
+        def program(base, w0, xs, ys):
             k = xs.shape[0]
             ws0 = jax.tree.map(
                 lambda a: jnp.broadcast_to(a.astype(jnp.float32),
                                            (k,) + a.shape), w0)
 
             def body(ws, _):
-                new_ws, _out, _norms = jax.vmap(
-                    lambda p, x, y: shard_round(p, x, y, epochs, "stacked")
+                new_ws, *_ = jax.vmap(
+                    lambda p, x, y: shard_round(base, p, x, y, epochs, "stacked")
                 )(ws, xs, ys)
                 return new_ws, None
 
             final, _ = jax.lax.scan(body, ws0, None, length=g_rounds)
             return final
 
-        prog = jax.jit(program)
+        prog = self._jit(program)
         self._stage_programs[key] = prog
         return prog
 
@@ -456,14 +502,15 @@ class FLSimulator:
         return fisher
 
     # ------------------------------------------------------------- evaluate
-    def _eval_stats_fn(self, stacked_models, xb, yb):
+    def _eval_stats_fn(self, base, stacked_models, xb, yb):
         """One jitted pass over all eval batches: ``predict_fn`` vmapped over
         the stacked (K, ...) ensemble, ``lax.scan`` over the (B, batch, ...)
         batches, correct/loss accumulated on device."""
         def body(carry, xy):
             x, y = xy
             b = self._make_batch(x, y)
-            logits = jax.vmap(lambda m: self._pf(m, b))(stacked_models)
+            logits = jax.vmap(lambda m: self._predict(merge_adapters(base, m), b)
+                              )(stacked_models)
             lg = logits.astype(jnp.float32).sum(0) / logits.shape[0]
             ll = jax.nn.log_softmax(lg, -1)
             correct = (lg.argmax(-1) == y).sum()

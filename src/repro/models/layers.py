@@ -31,7 +31,8 @@ def init_norm(fac: ParamFactory, cfg: ModelConfig, name: str):
     return {"scale": fac.param(f"{name}.scale", (cfg.d_model,), ("embed",), init="ones")}
 
 
-def apply_norm(p, x, cfg: ModelConfig, eps: float = 1e-6):
+def apply_norm(p, x, cfg: ModelConfig):
+    eps = cfg.norm_eps
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
     if cfg.norm_type == "layernorm" or cfg.norm_type == "nonparametric":
@@ -172,10 +173,11 @@ onehot_lookup.defvjp(_onehot_lookup_fwd, _onehot_lookup_bwd)
 
 def apply_unembed(p, x, cfg: ModelConfig):
     v = pad_vocab(cfg.vocab_size)
-    if cfg.tie_embeddings:
-        logits = x @ p["table"].T
-    else:
-        logits = x @ p["unembed"]
+    # float32 logits: a low-precision compute dtype keeps its float32
+    # accumulation here, for the softmax and the loss
+    w = p["table"].T if cfg.tie_embeddings else p["unembed"]
+    logits = jnp.matmul(x, w, preferred_element_type=jnp.promote_types(
+        jnp.result_type(x, w), jnp.float32))
     if cfg.logit_softcap:
         logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
     # mask padded vocab entries

@@ -1,6 +1,8 @@
 """Top-level model API: init / loss / serve, uniform across families.
 
 ``init_params(cfg, rng)``        -> param pytree (real arrays)
+``init_adapters(cfg, rng)``      -> LoRA adapter pytree (float32), and
+``merge_adapters(base, adapters)`` the full tree the forward reads
 ``param_axes(cfg)``              -> parallel pytree of logical-axis tuples
 ``abstract_params(cfg, dtype)``  -> ShapeDtypeStruct pytree (no allocation)
 ``loss_fn(cfg)(params, batch)``  -> (loss, metrics)  [train objective]
@@ -30,6 +32,18 @@ def _init(cfg: ModelConfig, fac: ParamFactory):
 def init_params(cfg: ModelConfig, rng: Optional[jax.Array] = None):
     rng = rng if rng is not None else jax.random.key(0)
     return _init(cfg, RealInit(rng, jnp.dtype(cfg.param_dtype)))
+
+
+def init_adapters(cfg: ModelConfig, rng: Optional[jax.Array] = None):
+    """float32 rank-``cfg.lora_rank`` LoRA adapters of every MLA projection."""
+    rng = rng if rng is not None else jax.random.key(0)
+    return tfm.init_lora(RealInit(rng, jnp.float32), cfg)
+
+
+def merge_adapters(base, adapters):
+    """The frozen base with the adapters in place: what ``loss_fn`` reads
+    (without a base, ``adapters`` are the whole model)."""
+    return adapters if base is None else tfm.merge_lora(base, adapters)
 
 
 def param_axes(cfg: ModelConfig):
@@ -73,9 +87,13 @@ def loss_fn(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX, remat: str = "block"):
         return cnn_loss
 
     def lm_loss(params, batch):
-        logits, aux = tfm.forward_train(params, cfg, batch, ctx, remat=remat)
+        logits, aux, load = tfm.forward_train(params, cfg, batch, ctx,
+                                              remat=remat)
         loss = _xent(logits, batch["labels"]) + aux
-        return loss, {"loss": loss, "aux": aux}
+        metrics = {"loss": loss, "aux": aux}
+        if load is not None:
+            metrics["expert_load"] = load
+        return loss, metrics
 
     return lm_loss
 
@@ -86,7 +104,7 @@ def predict_fn(cfg: ModelConfig, ctx: ShardCtx = NULL_CTX):
         return lambda params, batch: cnn_forward(params, batch["images"])
 
     def fwd(params, batch):
-        logits, _ = tfm.forward_train(params, cfg, batch, ctx, remat="none")
+        logits, _, _ = tfm.forward_train(params, cfg, batch, ctx, remat="none")
         return logits
 
     return fwd

@@ -7,6 +7,11 @@ the lowered HLO — exactly the collective the roofline wants to see.
 
 Tokens are routed within fixed-size groups (``group_size``) so dispatch cost
 is O(S * group * k) rather than O(S^2 * k).
+
+The DeepSeek-V3 layer (``moe_gate="sigmoid"``, ``apply_moe_held``) is told
+which experts it holds: routing runs over all ``num_experts``, and only the
+held experts' part of the result is computed, for every token routed to them
+(no capacity, no token dropped), plus the shared experts.
 """
 from __future__ import annotations
 
@@ -18,11 +23,18 @@ from repro.models.layers import ACTS
 from repro.models.params import ParamFactory
 
 
+# scale of the drawn correction bias of the sigmoid gate (a trained
+# checkpoint's is learnt; it only moves which experts are selected)
+ROUTER_BIAS_STD = 0.05
+
+
 def init_moe(fac: ParamFactory, cfg: ModelConfig):
-    d, e, f = cfg.d_model, cfg.num_experts, cfg.moe_d_ff
+    d, f = cfg.d_model, cfg.moe_d_ff
+    e = cfg.experts_held or cfg.num_experts
     with fac.scope("moe"):
-        return {
-            "router": fac.param("router", (d, e), ("embed", "expert_router")),
+        p = {
+            "router": fac.param("router", (d, cfg.num_experts),
+                                ("embed", "expert_router")),
             "wi_gate": fac.param("wi_gate", (e, d, f), ("expert", "embed", "mlp"),
                                  fan_in=d),
             "wi_up": fac.param("wi_up", (e, d, f), ("expert", "embed", "mlp"),
@@ -30,6 +42,67 @@ def init_moe(fac: ParamFactory, cfg: ModelConfig):
             "wo": fac.param("wo", (e, f, d), ("expert", "mlp", "embed"),
                             fan_in=f),
         }
+        if cfg.moe_gate == "sigmoid":
+            p["router_bias"] = fac.param("router_bias", (cfg.num_experts,),
+                                         ("expert_router",),
+                                         scale=ROUTER_BIAS_STD, fan_in=1)
+        if cfg.moe_shared_d_ff:
+            fs = cfg.moe_shared_d_ff
+            p["shared"] = {
+                "wi_gate": fac.param("shared.wi_gate", (d, fs), ("embed", "mlp")),
+                "wi_up": fac.param("shared.wi_up", (d, fs), ("embed", "mlp")),
+                "wo": fac.param("shared.wo", (fs, d), ("mlp", "embed"))}
+        return p
+
+
+def route_sigmoid(p, x, cfg: ModelConfig):
+    """DeepSeek-V3's noaux_tc gate with one group: ``(weights, experts)``,
+    each ``(..., k)``.  Scores are ``sigmoid(x @ router)`` in float32; the
+    experts are the top k of scores plus the correction bias; the weights
+    are their scores, normalised to sum to one, times ``moe_routed_scale``."""
+    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(
+        scores + p["router_bias"].astype(jnp.float32), cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, experts, axis=-1)
+    w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * cfg.moe_routed_scale, experts
+
+
+def held_weights(w, experts, first: int, held: int):
+    """``(..., held)`` weight of each held expert ``first + j`` for each
+    token: its gate weight where chosen, else 0."""
+    ids = first + jnp.arange(held)
+    return jnp.sum(jnp.where(experts[..., None] == ids, w[..., None], 0.0),
+                   axis=-2)
+
+
+def apply_moe_held(p, x, cfg: ModelConfig, first_expert: int = 0):
+    """x: (B, S, d) -> (y, load): the held experts' part of the routed
+    experts' sum plus the shared experts, and ``load`` (held,), the
+    tokens routed to each held expert.  Every held expert runs on every
+    token, weighted by its gate weight (0 where not chosen), so no token is
+    dropped whatever the routing; experts ``first_expert + j`` are held."""
+    act = ACTS[cfg.act]
+    held = p["wo"].shape[0]
+    with jax.named_scope("moe.route"):
+        w, experts = route_sigmoid(p, x, cfg)
+        wh = held_weights(w, experts, first_expert, held)
+        chosen = (experts[..., None] == first_expert + jnp.arange(held))
+        load = chosen.sum(axis=tuple(range(chosen.ndim - 1))).astype(jnp.float32)
+    with jax.named_scope("moe.experts"):
+        h = act(jnp.einsum("bsd,edf->bsef", x, p["wi_gate"].astype(x.dtype))) * \
+            jnp.einsum("bsd,edf->bsef", x, p["wi_up"].astype(x.dtype))
+        y = jnp.einsum("bsef,efd->bsd", h * wh[..., None].astype(x.dtype),
+                       p["wo"].astype(x.dtype))
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            sp = p["shared"]
+            hs = act(x @ sp["wi_gate"].astype(x.dtype)) * (x @ sp["wi_up"].astype(x.dtype))
+            y = y + hs @ sp["wo"].astype(x.dtype)
+    return y, load
 
 
 def _route(p, xg, cfg: ModelConfig, cap: int):

@@ -7,7 +7,8 @@ parameter stacks (remainder layers unrolled). This keeps HLO size ~constant in
 depth, which matters for 62-72 layer models compiled on the CPU dry-run host.
 
 Three modes:
-  train    -> logits over the full sequence (plus MoE aux loss)
+  train    -> logits over the full sequence (plus MoE aux loss and the
+              tokens routed to each held expert)
   prefill  -> logits + a populated decode cache
   decode   -> one-token step against the cache (``serve_step``'s body)
 """
@@ -23,10 +24,11 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
 from repro.models import mamba as mb
+from repro.models import mla
 from repro.models import rwkv6 as rw
 from repro.models.layers import (apply_embed, apply_mlp, apply_norm,
                                  apply_unembed, init_embed, init_mlp, init_norm)
-from repro.models.moe import apply_moe, init_moe
+from repro.models.moe import apply_moe, apply_moe_held, init_moe
 from repro.models.params import ParamFactory
 
 
@@ -62,9 +64,11 @@ NULL_CTX = ShardCtx()
 # ---------------------------------------------------------------------------
 
 def pattern_info(cfg: ModelConfig) -> Tuple[int, int, int]:
+    """(pattern length, full repetitions, remainder) of the layers after
+    the leading dense ones (``first_dense_layers``, unrolled first)."""
     plen = len(cfg.layer_pattern)
-    n_full = cfg.num_layers // plen
-    rem = cfg.num_layers % plen
+    n_full = cfg.layers_in_stack // plen
+    rem = cfg.layers_in_stack % plen
     if cfg.num_experts and n_full > 1:
         assert plen % cfg.moe_every == 0, (
             "layer_pattern length must be a multiple of moe_every so the "
@@ -93,9 +97,15 @@ class _Stacked(ParamFactory):
 
 
 def _init_block(fac: ParamFactory, cfg: ModelConfig, kind: str, pat_idx: int,
-                cross: bool = False):
+                cross: bool = False, dense: bool = False):
     p: Dict[str, Any] = {}
-    if kind in ("global", "local"):
+    if kind == "mla":
+        p["ln1"] = init_norm(fac, cfg, "ln1")
+        p["attn"] = mla.init_mla(fac, cfg)
+        p["ln2"] = init_norm(fac, cfg, "ln2")
+        p["ffn"] = (init_moe(fac, cfg) if cfg.ffn_is_moe(pat_idx) and not dense
+                    else init_mlp(fac, cfg))
+    elif kind in ("global", "local"):
         p["ln1"] = init_norm(fac, cfg, "ln1")
         p["attn"] = init_attention_wrap(fac, cfg)
         if cross:
@@ -126,6 +136,12 @@ def init_lm(fac: ParamFactory, cfg: ModelConfig):
     plen, n_full, rem = pattern_info(cfg)
     cross = cfg.family == "audio"
     params: Dict[str, Any] = {"embed": init_embed(fac, cfg)}
+    if cfg.first_dense_layers:
+        params["lead"] = {}
+        for j in range(cfg.first_dense_layers):
+            with fac.scope(f"lead{j}"):
+                params["lead"][f"l{j}"] = _init_block(
+                    fac, cfg, cfg.layer_pattern[0], 0, dense=True)
     if cfg.frontend:
         with fac.scope("frontend_proj"):
             params["frontend_proj"] = fac.param(
@@ -158,6 +174,38 @@ def init_lm(fac: ParamFactory, cfg: ModelConfig):
         params["enc_ln"] = init_norm(fac, cfg, "enc_ln")
     params["final_ln"] = init_norm(fac, cfg, "final_ln")
     return params
+
+
+def init_lora(fac: ParamFactory, cfg: ModelConfig):
+    """The LoRA adapter tree: the base tree's ``lead`` and ``stack`` blocks,
+    each MLA block's ``attn`` holding only its ``lora`` entry (``merge_lora``
+    puts it in place)."""
+    plen, n_full, rem = pattern_info(cfg)
+    if rem or any(k != "mla" for k in cfg.layer_pattern):
+        raise ValueError("LoRA adapters are defined for MLA stacks without "
+                         "remainder layers")
+    out: Dict[str, Any] = {}
+    if cfg.first_dense_layers:
+        out["lead"] = {}
+        for j in range(cfg.first_dense_layers):
+            with fac.scope(f"lead{j}"):
+                out["lead"][f"l{j}"] = mla.init_mla_lora(fac, cfg)
+    sfac = _Stacked(fac, n_full)
+    out["stack"] = {}
+    for pidx in range(plen):
+        with fac.scope(f"stack_p{pidx}"):
+            out["stack"][f"p{pidx}"] = mla.init_mla_lora(sfac, cfg)
+    return out
+
+
+def merge_lora(base, adapters):
+    """The base tree with the adapter tree's entries added in place."""
+    if not isinstance(adapters, dict):
+        return adapters
+    out = dict(base)
+    for k, v in adapters.items():
+        out[k] = merge_lora(base.get(k, {}), v)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -244,25 +292,41 @@ def _full_positions(cache_slots: int, pos):
 # ---------------------------------------------------------------------------
 
 def _apply_ffn(p, x, cfg: ModelConfig, is_moe: bool, ctx: ShardCtx):
-    if is_moe:
+    """(y, aux loss, load): ``load`` (held,) counts the tokens routed to
+    each held expert of a sigmoid-gated layer, else None."""
+    load = None
+    if is_moe and cfg.moe_gate == "sigmoid":
+        y, load = apply_moe_held(p, x, cfg)
+        aux = jnp.zeros((), jnp.float32)
+    elif is_moe:
         y, aux = apply_moe(p, x, cfg, ctx=ctx)
     else:
         y, aux = apply_mlp(p, x, cfg), jnp.zeros((), jnp.float32)
-    return y, jnp.asarray(aux, jnp.float32)
+    return y, jnp.asarray(aux, jnp.float32), load
 
 
 def apply_block_train(p, x, cfg: ModelConfig, kind: str, pat_idx: int,
                       ctx: ShardCtx, memory=None, positions=None,
-                      want_kv: bool = False):
-    """Train/prefill. Returns (x, aux, kv|None)."""
+                      want_kv: bool = False, dense: bool = False):
+    """Train/prefill. Returns (x, aux, kv|None, load|None); ``dense`` forces
+    the dense FFN (a leading dense layer)."""
     kv = None
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
+    is_moe = cfg.ffn_is_moe(pat_idx) and not dense
+    if kind == "mla":
+        if want_kv:
+            raise ValueError("MLA layers have no decode cache here")
+        x = x + mla.apply_mla(p["attn"], apply_norm(p["ln1"], x, cfg), cfg,
+                              positions)
+        h2 = apply_norm(p["ln2"], x, cfg)
+        y, aux, load = _apply_ffn(p["ffn"], h2, cfg, is_moe, ctx)
+        return ctx.constrain(x + y, ("batch", "seq", "embed")), aux, None, load
     if kind in ("global", "local"):
         h = apply_norm(p["ln1"], x, cfg)
         q = jnp.einsum("bsd,dhe->bshe", h, p["attn"]["wq"])
         k = jnp.einsum("bsd,dke->bske", h, p["attn"]["wk"])
         v = jnp.einsum("bsd,dke->bske", h, p["attn"]["wv"])
-        if positions is None:
-            positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
         from repro.models.layers import apply_rope
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
@@ -288,19 +352,19 @@ def apply_block_train(p, x, cfg: ModelConfig, kind: str, pat_idx: int,
             ox = attn.blockwise_attention(qx, kx, vx, causal=False)
             x = x + jnp.einsum("bshe,hed->bsd", ox, p["xattn"]["wo"])
         h2 = apply_norm(p["ln2"], x, cfg)
-        y, aux = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
+        y, aux, load = _apply_ffn(p["ffn"], h2, cfg, is_moe, ctx)
         x = x + y
-        return ctx.constrain(x, ("batch", "seq", "embed")), aux, kv
+        return ctx.constrain(x, ("batch", "seq", "embed")), aux, kv, load
     if kind == "mamba":
         h = apply_norm(p["ln1"], x, cfg)
         y, state = mb.mamba_block(p["mamba"], h, cfg)
         x = x + y
         h2 = apply_norm(p["ln2"], x, cfg)
-        y, aux = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
+        y, aux, load = _apply_ffn(p["ffn"], h2, cfg, is_moe, ctx)
         x = x + y
         if want_kv:  # prefill: carry final (conv, ssm) states into the cache
             kv = {"conv": state[0], "h": state[1]}
-        return ctx.constrain(x, ("batch", "seq", "embed")), aux, kv
+        return ctx.constrain(x, ("batch", "seq", "embed")), aux, kv, load
     if kind == "rwkv":
         b = x.shape[0]
         hh, nn = rw.rwkv_heads(cfg)
@@ -316,7 +380,7 @@ def apply_block_train(p, x, cfg: ModelConfig, kind: str, pat_idx: int,
         if want_kv:
             kv = {"tm_prev": tm_prev, "h": h_new, "cm_prev": cm_prev}
         return (ctx.constrain(x, ("batch", "seq", "embed")),
-                jnp.zeros((), jnp.float32), kv)
+                jnp.zeros((), jnp.float32), kv, None)
     raise ValueError(kind)
 
 
@@ -357,7 +421,7 @@ def apply_block_decode(p, x, cfg: ModelConfig, kind: str, pat_idx: int,
             ox = attn.decode_attention(qx, cache["xk"], cache["xv"], enc_pos)
             x = x + jnp.einsum("bshe,hed->bsd", ox, p["xattn"]["wo"])
         h2 = apply_norm(p["ln2"], x, cfg)
-        y, _aux = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
+        y, _aux, _load = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
         return x + y, new_cache
     if kind == "mamba":
         h = apply_norm(p["ln1"], x, cfg)
@@ -366,7 +430,7 @@ def apply_block_decode(p, x, cfg: ModelConfig, kind: str, pat_idx: int,
         new_cache["conv"], new_cache["h"] = conv2, h2s
         x = x + y
         h2 = apply_norm(p["ln2"], x, cfg)
-        y, _aux = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
+        y, _aux, _load = _apply_ffn(p["ffn"], h2, cfg, cfg.ffn_is_moe(pat_idx), ctx)
         return x + y, new_cache
     if kind == "rwkv":
         a, (tmp2, hs2) = rw.time_mix_step(
@@ -409,7 +473,9 @@ def encode_audio(params, cfg: ModelConfig, frames, ctx: ShardCtx):
 
 def forward_train(params, cfg: ModelConfig, batch, ctx: ShardCtx = NULL_CTX,
                   remat: str = "block"):
-    """Returns (logits, aux_loss). batch: tokens (B,S) [+ patches/frames]."""
+    """Returns (logits, aux_loss, load): ``load`` counts the tokens routed
+    to each held expert, (MoE layers, held), or is None without held
+    experts.  batch: tokens (B,S) [+ patches/frames]."""
     plen, n_full, rem = pattern_info(cfg)
     tokens = batch["tokens"]
     x = apply_embed(params["embed"], tokens, cfg).astype(jnp.dtype(cfg.compute_dtype))
@@ -424,15 +490,29 @@ def forward_train(params, cfg: ModelConfig, batch, ctx: ShardCtx = NULL_CTX,
     x = ctx.constrain(x, ("batch", "seq", "embed"))
     positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None]
     aux_total = 0.0
+    loads = []
+
+    def lead_block(x, block_params):
+        x, aux, _, _ = apply_block_train(block_params, x, cfg,
+                                         cfg.layer_pattern[0], 0, ctx,
+                                         positions=positions, dense=True)
+        return x, aux
+
+    for j in range(cfg.first_dense_layers):
+        body = jax.checkpoint(lead_block) if remat in ("block", "full") else lead_block
+        x, aux = body(x, params["lead"][f"l{j}"])
+        aux_total = aux_total + aux
 
     def superblock(x, block_params):
         aux_sb = jnp.zeros((), jnp.float32)
+        loads_sb = []
         for pidx, kind in enumerate(cfg.layer_pattern):
-            x, aux, _ = apply_block_train(block_params[f"p{pidx}"], x, cfg, kind,
-                                          pidx, ctx, memory=memory,
-                                          positions=positions)
+            x, aux, _, ld = apply_block_train(block_params[f"p{pidx}"], x, cfg,
+                                              kind, pidx, ctx, memory=memory,
+                                              positions=positions)
             aux_sb = aux_sb + aux
-        return x, aux_sb
+            loads_sb.append(ld)
+        return x, aux_sb, loads_sb
 
     if n_full:
         body = superblock
@@ -441,23 +521,29 @@ def forward_train(params, cfg: ModelConfig, batch, ctx: ShardCtx = NULL_CTX,
 
         def scan_body(carry, block_params):
             x, aux_acc = carry
-            x, aux_sb = body(x, block_params)
-            return (x, aux_acc + aux_sb), None
+            x, aux_sb, loads_sb = body(x, block_params)
+            return (x, aux_acc + aux_sb), loads_sb
 
-        (x, aux_total), _ = jax.lax.scan(
+        aux_lead = aux_total
+        (x, aux_total), stack_loads = jax.lax.scan(
             scan_body, (x, jnp.zeros((), jnp.float32)), params["stack"])
+        if cfg.first_dense_layers:
+            aux_total = aux_total + aux_lead
+        loads += [ld for ld in stack_loads if ld is not None]
     for j in range(rem):
         kind = cfg.layer_kinds[n_full * plen + j]
-        x, aux, _ = apply_block_train(params["rem"][f"r{j}"], x, cfg, kind,
-                                      j % plen, ctx, memory=memory,
-                                      positions=positions)
+        x, aux, _, ld = apply_block_train(params["rem"][f"r{j}"], x, cfg, kind,
+                                          j % plen, ctx, memory=memory,
+                                          positions=positions)
         aux_total = aux_total + aux
+        if ld is not None:
+            loads.append(ld[None])
     x = apply_norm(params["final_ln"], x, cfg)
     if n_prefix:
         x = x[:, n_prefix:]
     logits = apply_unembed(params["embed"], x, cfg)
     logits = ctx.constrain(logits, ("batch", "seq", "vocab"))
-    return logits, aux_total
+    return logits, aux_total, jnp.concatenate(loads) if loads else None
 
 
 def forward_prefill(params, cfg: ModelConfig, batch, ctx: ShardCtx = NULL_CTX,
@@ -491,9 +577,10 @@ def forward_prefill(params, cfg: ModelConfig, batch, ctx: ShardCtx = NULL_CTX,
     cache["pos"] = jnp.full((), total, jnp.int32)
 
     def run_block(p, x, kind, pidx, lead_cache):
-        x, _aux, kv = apply_block_train(p, x, cfg, kind, pidx, ctx,
-                                        memory=memory, positions=positions,
-                                        want_kv=True)
+        x, _aux, kv, _load = apply_block_train(p, x, cfg, kind, pidx, ctx,
+                                               memory=memory,
+                                               positions=positions,
+                                               want_kv=True)
         new_lc = dict(lead_cache)
         if isinstance(kv, dict):       # mamba/rwkv final states
             for name, val in kv.items():

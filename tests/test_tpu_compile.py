@@ -8,12 +8,16 @@ and compile it for one chip of a ``v5e:2x2`` topology that is described, not
 attached.  Nothing runs; a kernel passes when the compiler accepts it and
 the executable holds the kernel as a ``tpu_custom_call``.  One more test
 compiles the nanogpt-paper stage program, small in rounds and epochs, and
-reads in its HLO how the token embedding was lowered.
+reads in its HLO how the token embedding was lowered and that the program,
+metadata aside, is the one it has been since the simulator learnt to train
+adapters over a frozen base.
 
 The topology is described inside a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU library.
 """
+import hashlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -101,10 +105,9 @@ def test_wkv(one_chip):
     _assert_kernel(compiled)
 
 
-def test_nanogpt_stage_program_reads_embedding_by_matmul(one_chip):
-    # The nanogpt-paper stage program at G=1 and one epoch: its token
-    # embedding is a one-hot matmul with a matmul gradient, so the compiled
-    # program holds no gather, no scatter-add and no custom fusion of either.
+@pytest.fixture(scope="module")
+def nanogpt_stage_text(one_chip):
+    """The nanogpt-paper stage program at G=1 and one epoch, compiled."""
     from repro.configs import FLConfig, OptimizerConfig, get_config
     from repro.fl.simulator import FLSimulator
     from repro.models import init_params
@@ -122,7 +125,34 @@ def test_nanogpt_stage_program_reads_embedding_by_matmul(one_chip):
     data = _sds((shards, clients // shards, samples, SEQ), one_chip, jnp.int32)
     enc = _sds((clients, shards), one_chip)
     prog = sim._get_stage_program(1, "flat", 1, encode=True)
-    text = prog.lower(w0, data, data, enc).compile().as_text()
+    return prog.lower(w0, data, data, enc).compile().as_text()
+
+
+def test_nanogpt_stage_program_reads_embedding_by_matmul(nanogpt_stage_text):
+    # The token embedding is a one-hot matmul with a matmul gradient, so the
+    # compiled program holds no gather, no scatter-add and no custom fusion
+    # of either.
+    text = nanogpt_stage_text
     assert "embed.onehot" in text
     for banned in (" gather(", " scatter(", "kind=kCustom", "jit(_take)"):
         assert banned not in text, banned
+
+
+# sha256 of that program's text with its metadata stripped (op names, source
+# locations and the stack-frame tables), as it compiled before the simulator
+# took a frozen base for adapter-trained models: without adapters, the
+# shared stage path must compile to the very same program.
+NANOGPT_STAGE_SHA256 = \
+    "cc395509bf8373afb100051ba7b5eca1c6c82004f25981fdab49294d2be8652d"
+
+
+def strip_metadata(text: str) -> str:
+    text = re.sub(r"\nFileNames\n.*?\nStackFrames\n.*?\n\n", "\n", text,
+                  flags=re.S)
+    return re.sub(r",?\s*metadata=\{[^}]*\}", "", text)
+
+
+def test_nanogpt_stage_program_is_unchanged(nanogpt_stage_text):
+    stripped = strip_metadata(nanogpt_stage_text)
+    assert "op_name" not in stripped and "StackFrames" not in stripped
+    assert hashlib.sha256(stripped.encode()).hexdigest() == NANOGPT_STAGE_SHA256
