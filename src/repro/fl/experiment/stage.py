@@ -166,7 +166,9 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
     model with held experts also leaves the stage's tokens routed to each
     held expert, summed over rounds and clients, in ``record.expert_load``
     (MoE layers, held), and its mean and max over experts in the gauges
-    ``moe.expert_tokens{stat=mean|max}`` while a recording tracer is on.
+    ``moe.expert_tokens{stat=mean|max}`` while a recording tracer is on;
+    ``moe.expert_tokens{stat=fill}`` is the stage's held (token, expert)
+    pairs over the rows of the largest pair buffers of its MoE layers.
     """
     from repro.fl.simulator import StackedRoundGlobals, StageRecord
 
@@ -223,6 +225,9 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
                 float(per_expert.mean()))
             tr.metrics.gauge("moe.expert_tokens", stat="max").set(
                 float(per_expert.max()))
+            rows = sim.stage_pair_rows(xs.shape, fl.local_epochs, g_rounds)
+            tr.metrics.gauge("moe.expert_tokens", stat="fill").set(
+                float(per_expert.sum()) / (rows * per_expert.shape[0]))
     return StageRecord(plan, shard_models, round_globals, store,
                        history_norms=norms, expert_load=expert_load)
 

@@ -85,6 +85,7 @@ from repro.core.sharding import ShardManager, StagePlan
 from repro.fl.tasks import resolve_task
 from repro.models import (init_adapters, init_params, loss_fn, merge_adapters,
                           predict_fn)
+from repro.models.moe import pair_buffer_sizes
 from repro.optim import make_optimizer
 from repro.optim.fisher import diag_fisher, fisher_precondition
 
@@ -390,6 +391,19 @@ class FLSimulator:
         prog = self._jit(stage_program)
         self._stage_programs[key] = prog
         return prog
+
+    def stage_pair_rows(self, xs_shape, epochs: int, g_rounds: int) -> int:
+        """The rows of held-expert pair buffers that a stage program over
+        data ``(S, M, n, ...)`` runs in each MoE layer: one whole buffer a
+        step (``moe.pair_buffer_sizes``), for every token of the step, since
+        the program's vmaps over shards and clients pool their tokens into
+        one call of the layer."""
+        s, m, n = xs_shape[:3]
+        bs = self.local_batch
+        tokens = s * m * bs * int(np.prod(xs_shape[3:]))
+        held = self.cfg.experts_held or self.cfg.num_experts
+        rows = pair_buffer_sizes(tokens, self.cfg.experts_per_token, held)[-1]
+        return rows * g_rounds * epochs * (n // bs)
 
     def _get_retrain_program(self, epochs: int, g_rounds: int):
         """Lean whole-stage program for from-scratch retraining (the

@@ -11,14 +11,27 @@ is O(S * group * k) rather than O(S^2 * k).
 The DeepSeek-V3 layer (``moe_gate="sigmoid"``, ``apply_moe_held``) is told
 which experts it holds: routing runs over all ``num_experts``, and only the
 held experts' part of the result is computed, for every token routed to them
-(no capacity, no token dropped), plus the shared experts.
+(no capacity, no token dropped), plus the shared experts.  Its held experts
+run on the routed (token, expert) pairs alone: the pairs of every client and
+shard of a vmapped step are laid out expert by expert in one buffer, the
+smaller of two sizes that holds them (the larger has room for every token's
+held choices), and each expert weight is one grouped matmul over them
+(megablox ``gmm``, whose grid visits only the tiles that hold pairs).
+The expert weights are frozen (a base under LoRA adapters) and take no
+gradient.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+from jax.extend.core import Primitive
+from jax.interpreters import batching, mlir
 
 from repro.configs.base import ModelConfig
+from repro.kernels import on_tpu
 from repro.models.layers import ACTS
 from repro.models.params import ParamFactory
 
@@ -26,6 +39,16 @@ from repro.models.params import ParamFactory
 # scale of the drawn correction bias of the sigmoid gate (a trained
 # checkpoint's is learnt; it only moves which experts are selected)
 ROUTER_BIAS_STD = 0.05
+
+# tiles of the grouped matmul: rows of the pair buffer, and the widest tile
+# of a contracted or output width (v5e's scoped VMEM holds 512 x 1408)
+PAIR_TILE = 512
+WIDTH_TILE = 1408
+# the pair buffers a layer can run: the whole and a quarter; the smaller
+# that holds the step's held pairs runs, so the passes over the buffer follow
+# the routed pairs and not the worst case.  Each further size adds a copy of
+# the layer to the program, and set-up time with it
+BUFFER_PARTS = (1, 4)
 
 
 def init_moe(fac: ParamFactory, cfg: ModelConfig):
@@ -71,32 +94,216 @@ def route_sigmoid(p, x, cfg: ModelConfig):
     return w * cfg.moe_routed_scale, experts
 
 
-def held_weights(w, experts, first: int, held: int):
-    """``(..., held)`` weight of each held expert ``first + j`` for each
-    token: its gate weight where chosen, else 0."""
-    ids = first + jnp.arange(held)
-    return jnp.sum(jnp.where(experts[..., None] == ids, w[..., None], 0.0),
-                   axis=-2)
+def pair_buffer_sizes(t: int, k: int, held: int) -> list:
+    """The rows of the pair buffers a layer of ``t`` tokens can run,
+    smallest first, each whole tiles of the grouped matmul: the last holds
+    every token's ``min(k, held)`` held choices, the others
+    ``BUFFER_PARTS`` of it."""
+    whole = t * min(k, held)
+    return sorted({-(-whole // (part * PAIR_TILE)) * PAIR_TILE
+                   for part in BUFFER_PARTS})
+
+
+def _pair_layout(hidx, held: int):
+    """The (token, choice) pairs expert by expert.  ``hidx`` (T, k) names
+    each pair's held expert, ``held`` where its expert is not held.
+    Returns ``order`` (T*k,), the pairs sorted by held expert (those not
+    held last), so that a pair buffer's rows hold ``order``'s first pairs;
+    ``row`` (T, k), each pair's place in ``order``; and ``sizes`` (held,),
+    the groups' sizes."""
+    t, k = hidx.shape
+    flat = hidx.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    # the inverse of ``order`` by a second sort: on the TPU a sort of the
+    # pairs costs less than a scatter of them
+    row = jnp.argsort(order).astype(jnp.int32).reshape(t, k)
+    sizes = jnp.sum(flat[:, None] == jnp.arange(held), axis=0, dtype=jnp.int32)
+    return order, row, sizes
+
+
+def _tile(width: int) -> int:
+    """The widest tile of ``width`` up to ``WIDTH_TILE``: the width itself,
+    or its largest divisor that is a multiple of the 128 lanes."""
+    if width <= WIDTH_TILE:
+        return width
+    return max(t for t in range(128, WIDTH_TILE + 1, 128) if width % t == 0)
+
+
+def _tiling(m: int, k: int, n: int):
+    """Tiles of the grouped matmul: ``PAIR_TILE`` rows of the pair buffer
+    (``m`` is a multiple), and the contracted and the output width."""
+    return (PAIR_TILE, _tile(k), _tile(n))
+
+
+def _grouped(lhs, rhs, sizes, transpose=False, dtype=None):
+    """Rows of group ``e`` of ``lhs`` times ``rhs[e]`` (or its transpose),
+    accumulated in float32 and given in ``dtype`` (``lhs``'s by default).
+    Only the tiles that hold a group's rows run; rows past the groups'
+    total are left unwritten."""
+    return gmm(lhs, rhs, sizes, dtype or lhs.dtype, tiling=_tiling,
+               transpose_rhs=transpose, interpret=not on_tpu())
+
+
+def _combine(buf, row, held_pair):
+    """(T, d): the sum over each token's held pairs of their rows of the
+    float32 ``buf``, one choice at a time so that no (T, k, d) gather is
+    made; pairs not held add nothing (masked, never multiplied)."""
+    out = 0.0
+    for j in range(row.shape[1]):
+        picked = buf[jnp.where(held_pair[:, j], row[:, j], 0)]
+        out = out + jnp.where(held_pair[:, j, None], picked, 0.0)
+    return out
+
+
+def _smallest_buffer(fn, x, hidx, *args):
+    """``fn(pair, row, sizes, x, hidx, *args)`` with ``pair`` the pairs of
+    the smallest pair buffer that holds the held pairs, one a row (the rows
+    past the groups' total hold pairs that are not held): exact and dropless
+    whatever the routing, as the largest holds every token's held choices.
+    The layout is made once, outside the buffers' branches."""
+    held = args[-1].shape[0]
+    order, row, sizes = _pair_layout(hidx, held)
+    buffers = pair_buffer_sizes(x.shape[0], hidx.shape[1], held)
+    order = jnp.pad(order, (0, max(buffers[-1] - order.shape[0], 0)))
+
+    def first(rows):
+        return lambda order, *a: fn(order[:rows], *a)
+    branch = jnp.searchsorted(jnp.asarray(buffers), jnp.sum(sizes))
+    return jax.lax.switch(branch, [first(r) for r in buffers],
+                          order, row, sizes, x, hidx, *args)
+
+
+def _gate_up(pair, sizes, x, w, wgu, k: int):
+    """Each buffer row's token and gate weight, and the gate and up
+    projections of the rows, (rows, f) each in float32."""
+    tok = pair // k
+    gu = _grouped(x[tok], wgu, sizes)
+    f = gu.shape[1] // 2
+    return (tok, w.reshape(-1)[pair][:, None],
+            gu[:, :f].astype(jnp.float32), gu[:, f:].astype(jnp.float32))
+
+
+def _experts_forward(act, pair, row, sizes, x, hidx, w, wgu, wo):
+    """The held experts' part of the routed sum, (T, d): one grouped matmul
+    over the held (token, expert) pairs for the gate and up projections
+    together (``wgu``, (held, d, 2f)) and one for ``wo``, whose rows stay in
+    float32 until a token's pairs are summed."""
+    _, wr, gate, up = _gate_up(pair, sizes, x, w, wgu, hidx.shape[1])
+    h = (act(gate) * up * wr).astype(x.dtype)
+    out = _grouped(h, wo, sizes, dtype=jnp.float32)
+    return _combine(out, row, hidx < wo.shape[0]).astype(x.dtype)
+
+
+def _experts_backward(act, pair, row, sizes, x, hidx, w, dy, wgu, wo):
+    """(dx, dw): the gradients of ``_experts_forward`` for the tokens and
+    the gate weights; the weights are frozen and take none."""
+    tok, wr, gate, up = _gate_up(pair, sizes, x, w, wgu, hidx.shape[1])
+    sg, act_vjp = jax.vjp(act, gate)
+    g = _grouped(dy[tok], wo, sizes, True).astype(jnp.float32)
+    dwr = jnp.sum(sg * up * g, axis=1)
+    dh = g * wr
+    (dgate,) = act_vjp(dh * up)
+    dgu = jnp.concatenate([dgate, dh * sg], axis=1).astype(x.dtype)
+    dxr = _grouped(dgu, wgu, sizes, True, jnp.float32)
+    held_pair = hidx < wo.shape[0]
+    dx = _combine(dxr, row, held_pair).astype(x.dtype)
+    dw = jnp.where(held_pair, dwr[jnp.where(held_pair, row, 0)], 0.0)
+    return dx, dw.astype(w.dtype)
+
+
+def _pooled_call(*args, act, backward: bool):
+    """The held experts' forward ``(y,)`` or backward ``(dx, dw)`` over token
+    arrays (T, ...) and the expert weights."""
+    fn = _experts_backward if backward else _experts_forward
+    out = _smallest_buffer(functools.partial(fn, act), *args)
+    return out if backward else (out,)
+
+
+def _pooled_shapes(x, hidx, w, *rest, act, backward: bool):
+    """The outputs' shapes: ``y`` or ``dx`` like ``x``, ``dw`` like ``w``."""
+    return [x, w] if backward else [x]
+
+
+def _pooled_batch(args, dims, *, act, backward: bool):
+    """Folds the batch axis into the token axis: every member of a vmap
+    (clients, shards) shares one call.  The weights are never batched."""
+    tokens = 4 if backward else 3
+    if any(d is not None for d in dims[tokens:]):
+        raise NotImplementedError(
+            "the held experts' weights are shared by the whole batch")
+    size = next(a.shape[d] for a, d in zip(args, dims) if d is not None)
+    toks = [jnp.moveaxis(a, d, 0) if d is not None
+            else jnp.broadcast_to(a, (size,) + a.shape)
+            for a, d in zip(args[:tokens], dims)]
+    out = pooled_experts_p.bind(
+        *(a.reshape((-1,) + a.shape[2:]) for a in toks), *args[tokens:],
+        act=act, backward=backward)
+    return [o.reshape((size, -1) + o.shape[1:]) for o in out], [0] * len(out)
+
+
+# The pooled forward and backward are a primitive of their own, so that the
+# layer is traced once, when the program is lowered; a ``custom_vmap`` would
+# trace it again at each level of vmap, which lengthens set-up.
+pooled_experts_p = Primitive("pooled_experts")
+pooled_experts_p.multiple_results = True
+pooled_experts_p.def_impl(_pooled_call)
+pooled_experts_p.def_abstract_eval(_pooled_shapes)
+mlir.register_lowering(pooled_experts_p,
+                       mlir.lower_fun(_pooled_call, multiple_results=True))
+batching.primitive_batchers[pooled_experts_p] = _pooled_batch
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def routed_experts(act, x, hidx, w, wgu, wo):
+    """x (T, d), hidx (T, k) the held expert of each choice (``held`` where
+    not held), w (T, k) its gate weight, ``wgu`` the gate and up weights
+    side by side (held, d, 2f) -> (T, d): the sum over each token's held
+    choices of gate weight times the expert's output.  Only the held pairs
+    are computed, all tokens of a vmapped batch in one grouped matmul a
+    weight; the expert weights take no gradient."""
+    (y,) = pooled_experts_p.bind(x, hidx, w, wgu, wo, act=act, backward=False)
+    return y
+
+
+def _routed_fwd(act, x, hidx, w, wgu, wo):
+    return routed_experts(act, x, hidx, w, wgu, wo), (x, hidx, w, wgu, wo)
+
+
+def _routed_bwd(act, res, dy):
+    x, hidx, w, wgu, wo = res
+    with jax.named_scope("moe.experts"):
+        dx, dw = pooled_experts_p.bind(x, hidx, w, dy, wgu, wo, act=act,
+                                       backward=True)
+    return dx, None, dw, None, None
+
+
+routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
 def apply_moe_held(p, x, cfg: ModelConfig, first_expert: int = 0):
     """x: (B, S, d) -> (y, load): the held experts' part of the routed
     experts' sum plus the shared experts, and ``load`` (held,), the
-    tokens routed to each held expert.  Every held expert runs on every
-    token, weighted by its gate weight (0 where not chosen), so no token is
-    dropped whatever the routing; experts ``first_expert + j`` are held."""
+    tokens routed to each held expert.  Experts ``first_expert + j`` are
+    held; each runs on the tokens routed to it and no other, with no
+    capacity, so no token is dropped whatever the routing."""
     act = ACTS[cfg.act]
     held = p["wo"].shape[0]
+    b, s, d = x.shape
     with jax.named_scope("moe.route"):
         w, experts = route_sigmoid(p, x, cfg)
-        wh = held_weights(w, experts, first_expert, held)
-        chosen = (experts[..., None] == first_expert + jnp.arange(held))
+        local = experts - first_expert
+        hidx = jnp.where((local >= 0) & (local < held), local, held)
+        chosen = hidx[..., None] == jnp.arange(held)
         load = chosen.sum(axis=tuple(range(chosen.ndim - 1))).astype(jnp.float32)
     with jax.named_scope("moe.experts"):
-        h = act(jnp.einsum("bsd,edf->bsef", x, p["wi_gate"].astype(x.dtype))) * \
-            jnp.einsum("bsd,edf->bsef", x, p["wi_up"].astype(x.dtype))
-        y = jnp.einsum("bsef,efd->bsd", h * wh[..., None].astype(x.dtype),
-                       p["wo"].astype(x.dtype))
+        y = jnp.zeros_like(x)
+        if held:
+            k = cfg.experts_per_token
+            wgu = jnp.concatenate([p["wi_gate"], p["wi_up"]], axis=2)
+            y = routed_experts(
+                act, x.reshape(b * s, d), hidx.reshape(b * s, k),
+                w.reshape(b * s, k), wgu.astype(x.dtype),
+                p["wo"].astype(x.dtype)).reshape(x.shape)
     if "shared" in p:
         with jax.named_scope("moe.shared"):
             sp = p["shared"]

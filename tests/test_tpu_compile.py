@@ -10,7 +10,10 @@ the executable holds the kernel as a ``tpu_custom_call``.  One more test
 compiles the nanogpt-paper stage program, small in rounds and epochs, and
 reads in its HLO how the token embedding was lowered and that the program,
 metadata aside, is the one it has been since the simulator learnt to train
-adapters over a frozen base.
+adapters over a frozen base.  Two more compile Moonlight's held-expert
+layer (vmapped over shards and clients, with its input gradient) and the
+tiny variant's stage program, and read in them that the held experts run
+as grouped matmuls over the routed pairs and take no weight gradient.
 
 The topology is described inside a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU library.
@@ -156,3 +159,87 @@ def test_nanogpt_stage_program_is_unchanged(nanogpt_stage_text):
     stripped = strip_metadata(nanogpt_stage_text)
     assert "op_name" not in stripped and "StackFrames" not in stripped
     assert hashlib.sha256(stripped.encode()).hexdigest() == NANOGPT_STAGE_SHA256
+
+
+def _grouped_matmuls(text):
+    """(output dims, op_name) of each grouped-matmul kernel in ``text``."""
+    return [(tuple(int(v) for v in dims.split(",")), name) for dims, name in
+            re.findall(r'= \w+\[([\d,]+)\][^\n]*custom_call_target='
+                       r'"tpu_custom_call"[^\n]*op_name="([^"]*jit\(gmm\)[^"]*)"',
+                       text)]
+
+
+def _expected(buffers, d, f):
+    """The grouped matmuls' output dims for each pair buffer: forward, the
+    gate and up projections together (rows, 2f) and the down projection
+    (rows, d); backward, the first again, then the down projection's
+    transpose (rows, f) and the others' (rows, d)."""
+    return sorted(dims for rows in buffers for dims in
+                  [(rows, 2 * f)] * 2 + [(rows, f)] + [(rows, d)] * 2)
+
+
+def test_held_experts_run_as_grouped_matmuls(one_chip, monkeypatch):
+    """At Moonlight's widths: two grouped matmuls forward and three backward
+    for each size of pair buffer (of all the batch's tokens), each under
+    ``moe.experts``; no tensor holds the held-expert axis beside the expert
+    or the model width but the weights (the dense layer, or a dense
+    fallback of a grouped matmul, would)."""
+    from repro.configs import get_config
+    from repro.models import moe
+    from repro.models.params import ShapeOnly
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    cfg = get_config("moonlight-16b-a3b-fedlora")
+    held, d, f = cfg.experts_held, cfg.d_model, cfg.moe_d_ff
+    p = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype),
+                     moe.init_moe(ShapeOnly(jnp.bfloat16), cfg))
+    shards, clients, seq = 2, 3, 128
+    x = _sds((shards, clients, 1, seq, d), one_chip, jnp.bfloat16)
+
+    def step(p, x):
+        def loss(x):
+            y, _ = moe.apply_moe_held(p, x, cfg)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+        return jax.vmap(jax.vmap(jax.grad(loss)))(x)
+    text = jax.jit(step).lower(p, x).compile().as_text()
+    kernels = _grouped_matmuls(text)
+    assert sorted(dims for dims, _ in kernels) == _expected(
+        moe.pair_buffer_sizes(shards * clients * seq, cfg.experts_per_token,
+                              held), d, f)
+    assert all("moe.experts" in name for _, name in kernels)
+    shapes = {tuple(int(v) for v in s.split(","))
+              for s in re.findall(r"\w+\[([\d,]+)\]", text)}
+    assert {s for s in shapes if held in s and (f in s or d in s)} <= {
+        (held, d, f), (held, f, d), (held, d, 2 * f)}
+
+
+def test_moonlight_stage_program_trains_no_expert_weight(one_chip,
+                                                          monkeypatch):
+    """The tiny variant's stage program: in each MoE layer two grouped
+    matmuls run forward and three backward (the rematerialised forward keeps
+    only the gate and up projections), all over pair buffers and under
+    ``moe.experts``; none has a weight's shape, so none is a gradient for the
+    frozen experts."""
+    from repro.configs import FLConfig, OptimizerConfig
+    from repro.fl.families import get_model_family
+    from repro.fl.simulator import FLSimulator
+    from repro.models import moe
+    monkeypatch.setattr(moe, "on_tpu", lambda: True)
+    cfg = get_model_family("moonlight").build(None)
+    fl = FLConfig(num_clients=8, clients_per_round=4, num_shards=2,
+                  local_epochs=1, global_rounds=2)
+    sim = FLSimulator(cfg, fl, {}, task="generation",
+                      opt_cfg=OptimizerConfig(name="sgd", lr=0.1,
+                                              grad_clip=0.0),
+                      local_batch=2, seed=5)
+    shape = lambda a: _sds(a.shape, one_chip, a.dtype)  # noqa: E731
+    xs = _sds((2, 2, 4, 6), one_chip, jnp.int32)
+    prog = sim._get_stage_program(1, "flat", 2, encode=True)
+    text = prog.func.lower(
+        jax.tree.map(shape, sim.base),
+        jax.tree.map(shape, sim.init_params(jax.random.key(5))),
+        xs, xs, _sds((4, 2), one_chip)).compile().as_text()
+    kernels = _grouped_matmuls(text)
+    assert sorted(dims for dims, _ in kernels) == _expected(
+        moe.pair_buffer_sizes(2 * 2 * 2 * 6, cfg.experts_per_token,
+                              cfg.experts_held), cfg.d_model, cfg.moe_d_ff)
+    assert all("moe.experts" in name for _, name in kernels)
