@@ -46,7 +46,7 @@ class Run:
     seeds: dict = field(default_factory=dict)
 
     def __post_init__(self):
-        self.seeds = common.subseeds(self.seed)
+        self.seeds = common.subseeds(self.seed, self.cfg.get("checkpoint_seed"))
 
     def log(self, what: str) -> None:
         print(f"bench: {time.perf_counter() - self.started:9.3f} s  {what}",

@@ -47,12 +47,22 @@ def metric_reader(name: str):
                    f"bench_metric_{name.replace('-', '_').replace('.', '_')}")
 
 
-def subseeds(seed: int) -> dict:
-    """Independent 31-bit seeds for each use, drawn from ``--seed``."""
+def subseeds(seed: int, checkpoint: Optional[int] = None) -> dict:
+    """Independent 31-bit seeds for each use, drawn from ``--seed``.  Given
+    the ``checkpoint_seed`` of a configuration that fine-tunes one fixed
+    checkpoint, the program's seed (the frozen base, the first adapters and
+    the stage plan) is drawn from it instead, as ``--seed checkpoint`` would
+    draw it, and only the client data follows ``--seed``."""
     import numpy as np
-    words = np.random.SeedSequence(int(seed)).generate_state(2)
-    names = ("data", "program")
-    return {n: int(w) % (2 ** 31 - 64) for n, w in zip(names, words)}
+
+    def draw(s: int) -> dict:
+        words = np.random.SeedSequence(int(s)).generate_state(2)
+        return {n: int(w) % (2 ** 31 - 64)
+                for n, w in zip(("data", "program"), words)}
+    out = draw(seed)
+    if checkpoint is not None:
+        out["program"] = draw(checkpoint)["program"]
+    return out
 
 
 class NoAccelerator(RuntimeError):

@@ -150,6 +150,16 @@ def setup(run):
     sim = program.simulator(run.cfg, run.seeds["program"], clients)
     first = program.run_stage(program.session(sim, run.cfg))
     run.log("first stage trained")
+    if first.expert_load is not None:
+        # the routing this run's checkpoint and corpus give: held (token,
+        # expert) pairs over the rows of the stage's whole pair buffers
+        held = np.asarray(jax.device_get(first.expert_load))
+        xs, _ = stacked_data(clients, first.plan.shard_clients)
+        rows = sim.stage_pair_rows(xs.shape, fed["local_epochs"],
+                                   fed["global_rounds"])
+        pairs = float(held.sum())
+        run.log(f"set-up stage: {pairs!r} held pairs, fill "
+                f"{pairs / (rows * held.shape[0])!r}")
     return clients, sim, first
 
 

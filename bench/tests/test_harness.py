@@ -112,6 +112,41 @@ def test_subseeds_are_31_bit_and_repeat(seed):
     assert a["data"] != a["program"] and a != common.subseeds(seed + 1)
 
 
+@pytest.mark.parametrize("seed, want", [
+    (0, {"data": 821328126, "program": 1529665575}),
+    (2 ** 31 + 5, {"data": 181218269, "program": 869972020}),
+    (2 ** 40 + 3, {"data": 642151732, "program": 1410910647})])
+def test_subseeds_without_a_checkpoint_keep_their_values(seed, want):
+    """A configuration with no ``checkpoint_seed`` draws both seeds from
+    ``--seed``, to the values these cells have always had."""
+    assert common.subseeds(seed) == common.subseeds(seed, None) == want
+
+
+@pytest.mark.parametrize("seeds", [(0, 2 ** 31 + 5), (2 ** 40 + 3, 7)])
+def test_checkpoint_seed_fixes_only_the_program_seed(seeds):
+    a, b = (common.subseeds(s, 5) for s in seeds)
+    assert a["program"] == b["program"] == common.subseeds(5)["program"]
+    assert [a["data"], b["data"]] == [common.subseeds(s)["data"] for s in seeds]
+    assert a["data"] != b["data"]
+    assert all(0 <= v < 2 ** 31 for v in (*a.values(), *b.values()))
+
+
+@pytest.mark.parametrize("name", ["nanogpt-paper", "cnn-paper",
+                                  "moonlight-16b-a3b-fedlora"])
+def test_run_draws_the_program_seed_from_the_configuration(name):
+    """Only a configuration that names its checkpoint fixes the program's
+    seed; the others follow ``--seed`` alone."""
+    from bench.harness.cell import Run
+    mod = common.config_module(name)
+    cp = mod.CONFIG.get("checkpoint_seed")
+    assert (cp is not None) == (name == "moonlight-16b-a3b-fedlora")
+    seed = 2 ** 33 + 7
+    run = Run(name=name, workload={}, cfg=mod.CONFIG, model=mod, seed=seed,
+              seconds=1.0, trace=False, devices=[], started=0.0)
+    assert run.seeds == common.subseeds(seed, cp)
+    assert run.seeds["data"] == common.subseeds(seed)["data"]
+
+
 def test_plan_splits_distinct_clients_into_equal_shards():
     from bench.harness.drivers import stage
     cfg = common.config_module("nanogpt-paper").CONFIG

@@ -73,13 +73,15 @@ def _tiny_cell(monkeypatch):
 
 def test_reference_is_the_program():
     """Same adapters from a seed, and the same loss and adapter gradients
-    to float32 round-off, at ``highest``."""
+    to float32 round-off, at ``highest``; the seed is the program's seed of
+    a run, drawn from the configuration's ``checkpoint_seed``."""
     from repro.models import init_adapters, init_params, loss_fn, merge_adapters
     tiny = _tiny_program()
     model = {k: getattr(tiny, k) for k in _KEYS}
     model["padded_vocab"] = 512
     ref, _ = _reference(model)
-    seed = 2 ** 31 + 11
+    checkpoint = common.config_module(CONFIG).CONFIG["checkpoint_seed"]
+    seed = common.subseeds(2 ** 31 + 11, checkpoint)["program"]
     ours, theirs = ref.init(seed), init_adapters(tiny, jax.random.key(seed))
     assert jax.tree.structure(ours) == jax.tree.structure(theirs)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
@@ -101,14 +103,47 @@ def test_reference_is_the_program():
                                    atol=2e-5 * scale, rtol=0)
 
 
-def test_sound_tiny_run_is_correct(monkeypatch):
+@pytest.mark.parametrize("checkpoint", [True, False])
+def test_sound_tiny_run_is_correct(monkeypatch, checkpoint):
+    """Correct on the configuration's fixed checkpoint, and on a checkpoint
+    drawn from ``--seed`` as a configuration without one draws it."""
     from bench import run as bench_run
     cfg, wl = _tiny_cell(monkeypatch)
+    assert "checkpoint_seed" in cfg
+    if not checkpoint:
+        del cfg["checkpoint_seed"]
     result, checks = bench_run.run_cell(CELL, 2 ** 33 + 7, 0.5, False,
                                         jax.devices()[:1], time.perf_counter(),
                                         cfg=cfg, workload=wl)
     assert result["correct"], checks
     assert result["metrics"]["stage_s"]["value"] > 0
+
+
+def test_checkpoint_seed_fixes_the_base_and_first_adapters(monkeypatch):
+    """Two ``--seed`` values over a configuration with ``checkpoint_seed``:
+    the same program seed (so the same stage plan), frozen base and first
+    adapters, and different client data."""
+    from bench.harness import program
+    from bench.harness.cell import Run
+    cfg, wl = _tiny_cell(monkeypatch)
+    mod = common.config_module(CONFIG)
+    runs = [Run(name=CELL, workload=wl, cfg=cfg, model=mod, seed=seed,
+                seconds=0.5, trace=False, devices=jax.devices()[:1],
+                started=time.perf_counter()) for seed in (2 ** 33 + 7, 12)]
+    assert runs[0].seeds["program"] == runs[1].seeds["program"]
+    assert runs[0].seeds["data"] != runs[1].seeds["data"]
+    fed = cfg["federation"]
+    clients = [mod.make_clients(r.seeds["data"], fed["num_clients"],
+                                cfg["data"]["samples_per_client"]) for r in runs]
+    assert any(not np.array_equal(clients[0][c][0], clients[1][c][0])
+               for c in clients[0])
+    sims = [program.simulator(cfg, r.seeds["program"], cs)
+            for r, cs in zip(runs, clients)]
+    firsts = [s.init_params(jax.random.key(s.seed)) for s in sims]
+    for trees in ([s.base for s in sims], firsts):
+        assert jax.tree.structure(trees[0]) == jax.tree.structure(trees[1])
+        for x, y in zip(*map(jax.tree.leaves, trees)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_half_batch_is_caught(monkeypatch):
