@@ -1,7 +1,7 @@
-"""Tracer overhead A/B/C on the fig6 stage-engine scenario.
+"""Tracer overhead A/B/C on one stage-training scenario.
 
-Three configurations of the SAME workload (one coded-store ``stage``-engine
-training stage at scale ``sc``, the fig6 steady-state protocol):
+Three configurations of the SAME workload (one coded-store training stage
+at scale ``sc``, timed warm as the median of ``ITERS`` stages):
 
 * ``off``     — the default ``NULL_TRACER``: every instrumentation site costs
   one ``get_tracer()`` call plus a no-op context manager.  The acceptance
@@ -29,14 +29,14 @@ ITERS = 3
 
 
 def _stage_wall(sc: Scale) -> float:
-    """Median wall (us) of a steady-state stage-engine training stage."""
+    """Median wall (us) of a steady-state training stage."""
     from repro.fl.experiment import train_stage
 
     sim, _ = build_image_sim(sc, iid=True)
-    train_stage(sim, store_kind="coded", engine="stage")   # warm the jit cache
+    train_stage(sim, store_kind="coded")   # warm the jit cache
     walls = []
     for _ in range(ITERS):
-        _, us = timed(train_stage, sim, store_kind="coded", engine="stage")
+        _, us = timed(train_stage, sim, store_kind="coded")
         walls.append(us)
     return statistics.median(walls)
 
@@ -49,7 +49,7 @@ def run(sc: Scale):
     set_tracer(NULL_TRACER)
     off_us = _stage_wall(sc)
     emit("fig10_tracer_off", off_us,
-         f"stage engine;coded;G={sc.global_rounds};median_of={ITERS}")
+         f"coded;G={sc.global_rounds};median_of={ITERS}")
 
     configure(enabled=True)
     on_us = _stage_wall(sc)

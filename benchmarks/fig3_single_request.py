@@ -9,6 +9,7 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import Scale, build_image_sim, build_lm_sim, emit
+from repro.fl.experiment import train_stage
 
 FRAMEWORKS = ("FR", "FE", "RR", "SE")
 
@@ -19,7 +20,7 @@ def run(sc: Scale, tasks=("image", "lm"), iids=(True, False)):
             tag = f"fig3_{task}_{'iid' if iid else 'noniid'}"
             sim, test = (build_image_sim if task == "image" else build_lm_sim)(
                 sc, iid=iid)
-            record = sim.train_stage(store_kind="coded")
+            record = train_stage(sim, store_kind="coded")
             base = sim.evaluate(record.shard_models, *test)
             emit(f"{tag}_trained", 0.0,
                  f"acc={base['acc']:.4f};loss={base['loss']:.4f}")

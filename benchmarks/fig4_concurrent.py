@@ -8,6 +8,7 @@ which always retrain the full federation.
 from __future__ import annotations
 
 from benchmarks.common import Scale, build_image_sim, build_lm_sim, emit
+from repro.fl.experiment import train_stage
 from repro.core.sharding import adaptive_requests, even_requests
 
 FRAMEWORKS = ("FR", "FE", "RR", "SE")
@@ -17,7 +18,7 @@ def run(sc: Scale, k: int = 4, tasks=("image", "lm")):
     for task in tasks:
         sim, test = (build_image_sim if task == "image" else build_lm_sim)(
             sc, iid=True)
-        record = sim.train_stage(store_kind="coded")
+        record = train_stage(sim, store_kind="coded")
         for pattern, reqfn in (("even", even_requests),
                                ("adapt", adaptive_requests)):
             requests = reqfn(record.plan, k)

@@ -25,7 +25,7 @@ def main(argv=None) -> None:
     from repro.compile_cache import configure_compile_cache
     print(f"# compile cache: {configure_compile_cache()}", flush=True)
     from benchmarks import (fig3_single_request, fig4_concurrent, fig5_storage,
-                            fig6_round_engine, fig7_service, fig8_faults,
+                            fig6_batched_unlearn, fig7_service, fig8_faults,
                             fig9_durability, fig10_telemetry, fig11_tiering,
                             kernels_bench, table1_f1_time, theory_check,
                             verify_bench)
@@ -63,7 +63,7 @@ def main(argv=None) -> None:
         "fig3": fig3_single_request.run,
         "fig4": fig4_concurrent.run,
         "fig5": fig5_storage.run,
-        "fig6": fig6_round_engine.run,
+        "fig6": fig6_batched_unlearn.run,
         "fig7": fig7_service.run,
         "fig8": fig8_faults.run,
         "fig9": fig9_durability.run,

@@ -64,7 +64,7 @@ def main(argv=None) -> int:
     configure(enabled=True)
     with tempfile.TemporaryDirectory() as tmp:
         sim, _ = build_image_sim(sc, iid=True)
-        session = FederatedSession(sim, store_kind="coded", engine="stage",
+        session = FederatedSession(sim, store_kind="coded",
                                    checkpoint_every=1, checkpoint_dir=tmp)
         # two checkpointed stages with one scheduled SE request after stage 0
         # — covers snapshot I/O, the session unlearning dispatch, and the

@@ -8,7 +8,7 @@ The one-chip run drives both paper tasks (generation: nanogpt-paper at its
 published widths; classification: the paper's CNN) at
 ``ScenarioConfig.paper_full`` (100 clients, 20 sampled per stage, S=4 shards,
 L=10 local epochs, G=30 rounds) through the normal entry points:
-``build_session`` -> ``FederatedSession.run_stage`` (engine="stage", coded
+``build_session`` -> ``FederatedSession.run_stage`` (the stage program, coded
 store) -> ``FederatedSession.unlearn`` (SE) -> ``UnlearningService.serve``.
 Weights come from the scenario's seed.
 
@@ -84,10 +84,10 @@ def _die(msg: str, code: int = 2):
 
 
 def paper_config(task: str, **overrides):
-    """The paper's full federation for ``task``, stage engine, coded store."""
+    """The paper's full federation for ``task``, coded store."""
     from repro.fl.experiment import ScenarioConfig
     return ScenarioConfig.paper_full(
-        **{"task": task, "engine": "stage", "store": "coded", **overrides})
+        **{"task": task, "store": "coded", **overrides})
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +164,7 @@ def _finite(tree) -> bool:
 
 
 def run_stage_strict(session):
-    """``run_stage`` with the stage engine's ragged-stage fallback raised as
+    """``run_stage`` with the per-shard fallback of a ragged stage raised as
     an error: the one-dispatch stage program must be what ran."""
     with warnings.catch_warnings():
         warnings.filterwarnings("error", message="ragged stage")

@@ -211,8 +211,8 @@ def encode_rounds(enc: jnp.ndarray, hist: jnp.ndarray,
     """All-rounds Lagrange encode: ``hist (G, S, P) -> (G, C, P)`` in one op.
 
     ``enc`` is the (C, S) encode matrix (``CodingScheme.encode_matrix`` as a
-    device array).  Fully traceable — this is the encode the stage-program
-    engine fuses *into* the training program, replacing ``encode_batched``'s
+    device array).  Fully traceable — this is the encode the stage program
+    fuses *into* the training program, replacing ``encode_batched``'s
     separate dispatch.  Per-round columns are identical math to
     ``encode(scheme, hist[g])``.  jnp path: one batched einsum over the round
     axis.  Kernel path: a (G, C_tiles, P_tiles)-grid Pallas matmul that

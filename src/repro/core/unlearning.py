@@ -42,8 +42,8 @@ def stacked_mean(stacked) -> object:
     device-resident replacement for unstack + ``tree_mean``.
 
     The reduction is a strict left fold over the M rows, matching
-    ``tree_mean``'s Python-``sum`` association exactly, so the fused round
-    engine reproduces the legacy per-client loop bit-for-bit (f32 adds are
+    ``tree_mean``'s Python-``sum`` association exactly, so stage training
+    reproduces a per-client ``tree_mean`` loop bit-for-bit (f32 adds are
     order-sensitive; XLA keeps strict semantics and still fuses the chain).
     """
     def mean_leaf(a):

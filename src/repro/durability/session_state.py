@@ -9,10 +9,11 @@ the ``SessionReport`` (including per-request ``UnlearnResult`` models),
 and the set of served request ids.
 
 ``restore_session`` rebuilds that state onto a *freshly constructed*
-session of the same configuration (same simulator seed / store kind /
-engine).  Because stage training is deterministic given the restored RNG
-state, a resumed ``run`` re-trains post-snapshot stages bit-identically —
-the durability acceptance test's whole premise.
+session of the same configuration (same simulator seed and store kind; a
+snapshot from before training had one path also carries an ``engine`` key,
+which is ignored).  Because stage training is deterministic given the
+restored RNG state, a resumed ``run`` re-trains post-snapshot stages
+bit-identically — the durability acceptance test's whole premise.
 """
 from __future__ import annotations
 
@@ -256,7 +257,6 @@ def capture_session(session) -> dict:
     return {
         "version": STATE_VERSION,
         "store_kind": session.store_kind,
-        "engine": session.engine,
         "seed": int(sim.seed),
         "num_stages": len(session.records),
         "rng_state": sim.mgr._rng.bit_generator.state,
@@ -274,12 +274,11 @@ def restore_session(session, state: dict) -> int:
     if state.get("version") != STATE_VERSION:
         raise ValueError(f"snapshot state version {state.get('version')!r} "
                          f"!= supported {STATE_VERSION}")
-    for knob in ("store_kind", "engine"):
-        if state[knob] != getattr(session, knob):
-            raise ValueError(
-                f"snapshot was taken with {knob}={state[knob]!r} but this "
-                f"session has {knob}={getattr(session, knob)!r}; resume "
-                f"needs an identically configured session")
+    if state["store_kind"] != session.store_kind:
+        raise ValueError(
+            f"snapshot was taken with store_kind={state['store_kind']!r} but "
+            f"this session has store_kind={session.store_kind!r}; resume "
+            f"needs an identically configured session")
     if state["seed"] != session.sim.seed:
         raise ValueError(f"snapshot seed {state['seed']} != simulator seed "
                          f"{session.sim.seed}; resumed training would "

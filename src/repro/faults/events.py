@@ -99,8 +99,8 @@ class FaultEvent:
 @dataclass(frozen=True)
 class DegradedModeEvent:
     """A subsystem degraded instead of failing: e.g. mid-stage client
-    dropout made the stage ragged, so the stage-program engine fell back to
-    the per-shard fused path (PR 3's ragged path) rather than raising."""
+    dropout made the stage ragged, so training fell back from the stage
+    program to the per-shard round rather than raising."""
     kind: str = field(default="degraded_mode", init=False)
     stage: int = 0
     reason: str = ""

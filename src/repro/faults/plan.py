@@ -5,8 +5,8 @@ A ``FaultPlan`` is a registry-style collection of seeded injectors
 threaded through the store, session, and service layers:
 
 * ``client_dropout``    — stage-level client churn: clients vanish from the
-  stage before their params are stored, making shards ragged (the training
-  engines degrade to the per-shard path instead of crashing).
+  stage before their params are stored, making shards ragged (training falls
+  back to the per-shard round instead of crashing).
 * ``straggler``         — per-job straggler delay in the serving path.
 * ``slice_erasure``     — coded slices become unreachable at read time
   (``CodedStore`` recovers via erasure decoding from any >= S survivors).

@@ -129,7 +129,7 @@ class UnlearnContext:
     def retrain_shards(self, w0, xs, ys, g_rounds: int):
         """From-scratch FedAvg of a stacked ``(K, M, n, ...)`` batch of
         shards at the FULL L local epochs in one dispatch (vmap-over-shards
-        × scan-over-rounds, reusing the stage engine's round body) — the
+        × scan-over-rounds, reusing the stage program's round body) — the
         exact-unlearning ground-truth pass.  Returns the ``(K, ...)`` final
         shard models."""
         prog = self.sim._get_retrain_program(self.fl.local_epochs, g_rounds)

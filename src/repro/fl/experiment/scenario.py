@@ -32,7 +32,6 @@ from repro.data.federated import get_partitioner
 from repro.fl.experiment.frameworks import FRAMEWORKS
 from repro.fl.experiment.session import (FederatedSession, RequestSchedule,
                                          SessionReport)
-from repro.fl.experiment.stage import ENGINES
 from repro.fl.families import get_model_family
 from repro.fl.simulator import FLSimulator
 from repro.fl.tasks import get_task
@@ -73,8 +72,6 @@ class ScenarioConfig:
     # factory-specific store knobs passed through make_store verbatim (e.g.
     # store="tiered": hot_bytes / warm_bytes / eviction / offload_dir)
     store_options: Dict[str, Any] = field(default_factory=dict)
-    engine: str = "fused"                # "stage" | "fused" | "legacy"
-    encode_group: Optional[int] = None
     slice_dtype: Optional[DTypeLike] = None
     num_stages: int = 1
     schedule: Optional[RequestSchedule] = None
@@ -105,9 +102,6 @@ class ScenarioConfig:
         if self.store not in STORES:
             raise ValueError(f"unknown store {self.store!r}; registered: "
                              f"{sorted(STORES)}")
-        if self.engine not in ENGINES:
-            raise ValueError(f"unknown engine {self.engine!r}; use one of "
-                             f"{ENGINES}")
         if self.schedule is not None:
             for r in self.schedule.requests:
                 if r.framework not in FRAMEWORKS:
@@ -215,8 +209,7 @@ def build_simulator(cfg: ScenarioConfig) -> Tuple[FLSimulator, TestData]:
 def build_session(cfg: ScenarioConfig) -> Tuple[FederatedSession, TestData]:
     """Simulator wrapped in a session configured from the scenario."""
     sim, test = build_simulator(cfg)
-    session = FederatedSession(sim, store_kind=cfg.store, engine=cfg.engine,
-                               encode_group=cfg.encode_group,
+    session = FederatedSession(sim, store_kind=cfg.store,
                                slice_dtype=cfg.slice_dtype,
                                batch_requests=cfg.batch_requests,
                                strict_schedule=cfg.strict_schedule,

@@ -156,13 +156,17 @@ class FederatedSession:
     >>> report = session.run(num_stages=3, schedule=schedule)
     """
 
-    def __init__(self, sim, store_kind: str = "coded", engine: str = "fused",
-                 encode_group: Optional[int] = None, slice_dtype=None,
+    def __init__(self, sim, store_kind: str = "coded", engine: str = "stage",
+                 slice_dtype=None,
                  rounds: Optional[int] = None, batch_requests: bool = False,
                  strict_schedule: bool = False, faults=None,
                  checkpoint_every: int = 0,
                  checkpoint_dir: Optional[str] = None,
                  store_options: Optional[dict] = None):
+        # the benchmark's configurations still name the one training path
+        if engine != "stage":
+            raise ValueError(f"unknown engine {engine!r}; stage training has "
+                             f"one path, engine='stage'")
         if checkpoint_every and not checkpoint_dir:
             raise ValueError(
                 f"checkpoint_every={checkpoint_every} needs a "
@@ -174,8 +178,6 @@ class FederatedSession:
         self.sim = sim
         self.store_kind = store_kind
         self.store_options = dict(store_options or {})
-        self.engine = engine
-        self.encode_group = encode_group
         self.slice_dtype = slice_dtype
         self.rounds = rounds
         self.batch_requests = batch_requests
@@ -206,11 +208,9 @@ class FederatedSession:
         tr = get_tracer()
         t0 = time.perf_counter()
         with tr.span("session.stage", stage=len(self.records),
-                     engine=self.engine, store=self.store_kind):
+                     store=self.store_kind):
             record = train_stage(self.sim, store_kind=self.store_kind,
                                  rounds=rounds or self.rounds,
-                                 engine=self.engine,
-                                 encode_group=self.encode_group,
                                  slice_dtype=self.slice_dtype,
                                  faults=self.faults,
                                  store_options=self.store_options)

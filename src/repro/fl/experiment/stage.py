@@ -5,24 +5,23 @@ This is the training half of the experiment layer: ``train_stage(sim, ...)``
 runs G FedAvg rounds for every shard of a freshly sampled stage and writes
 each round's parameters into the store through the single
 ``ParameterStore.put_round(RoundPayload)`` entry point.  The store's
-``wants`` attribute tells the engine which payload form to compute *inside*
-the jitted round step ("flat" for the coded store, "stacked" for the uncoded
-ones), so the store choice never forces a host round-trip.
+``wants`` attribute tells the round step which payload form to compute
+*inside* the jitted program ("flat" for the coded store, "stacked" for the
+uncoded ones), so the store choice never forces a host round-trip.
 
-Three engines (dispatch count per stage in parentheses):
+There is one training path, chosen by the stage's own shape:
 
-* ``engine="stage"`` — the whole-stage superfusion (O(1)): shard data stacked
-  to (S, M, n, ...), ``shard_round`` vmapped over shards, ``lax.scan`` over
-  the G rounds, and the coded store's Lagrange encode fused into the same
-  program — one dispatch produces final models, round globals, update norms,
-  and the coded slices.  Ragged stages (unequal client or sample counts per
-  shard) degrade gracefully to the fused per-shard path.
-* ``engine="fused"`` (default) — one jitted ``shard_round`` per (shard,
-  round) plus one deferred batched encode (G·S + 1).
-* ``engine="legacy"`` — the seed per-client path (≫ G·S·M), kept for A/B
-  benchmarking.
-
-``FLSimulator.train_stage`` is a deprecated shim over this function.
+* A stage that stacks — every shard holds the same number of clients with
+  the same per-client sample count — runs as ONE dispatch: shard data
+  stacked to (S, M, n, ...), ``shard_round`` vmapped over shards,
+  ``lax.scan`` over the G rounds, and the coded store's Lagrange encode
+  fused into the same program (``_run_stage_program``).
+* A ragged stage (fault-plan client dropout, or unequal sample counts)
+  cannot be stacked, so it runs the per-shard round: one jitted
+  ``shard_round`` per (shard, round) and one batched encode of all G rounds
+  (``_run_per_shard``).  Under a fault plan the fallback is recorded as a
+  ``DegradedModeEvent``, otherwise it warns.  The two paths give the same
+  shard models bit for bit on the CPU.
 """
 from __future__ import annotations
 
@@ -34,56 +33,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.stores.store import RoundPayload
-from repro.core import coding, unlearning
+from repro.core import coding
 from repro.telemetry import get_tracer
-
-ENGINES = ("stage", "fused", "legacy")
 
 
 def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
-                engine: str = "fused", encode_group: Optional[int] = None,
                 slice_dtype=None, faults=None, store_options=None):
     """One stage: sample clients, split into shards, G FedAvg rounds per
     shard, storing intermediate params in the requested (registered) store.
 
-    ``engine`` selects the round engine (see module docstring):
-    ``"stage"`` (one dispatch per stage), ``"fused"`` (default, one per
-    shard-round), or ``"legacy"`` (the seed per-client path, for A/B).
-    ``encode_group`` batches that many rounds per coded encode on the fused
-    engine (default: all G in one; the stage engine always encodes all G
-    inside the program).  ``slice_dtype`` optionally stores coded slices in
-    e.g. bf16.  ``store_options`` passes factory-specific knobs through to
-    the registered store (e.g. ``store_kind="tiered"`` budgets/eviction).
+    ``slice_dtype`` optionally stores coded slices in e.g. bf16.
+    ``store_options`` passes factory-specific knobs through to the
+    registered store (e.g. ``store_kind="tiered"`` budgets/eviction).
 
     ``faults`` (a ``repro.faults.FaultPlan``) applies the plan's client
     dropout to the freshly sampled stage (clients vanish before training —
-    shards may go ragged, which the stage engine tolerates by degrading to
-    the per-shard fused path, recorded as a ``DegradedModeEvent`` instead of
-    a warning) and attaches the plan's slice injectors to the stage's store.
+    shards may go ragged, which falls back to the per-shard round, recorded
+    as a ``DegradedModeEvent`` instead of a warning) and attaches the plan's
+    slice injectors to the stage's store.
 
     Returns a ``StageRecord``.
     """
-    if engine not in ENGINES:
-        raise ValueError(f"unknown engine {engine!r}; use one of {ENGINES}")
-    if engine == "legacy":
-        if encode_group is not None or slice_dtype is not None:
-            raise ValueError("encode_group/slice_dtype need engine="
-                             "'fused' or 'stage'")
-        if faults is not None:
-            raise ValueError("fault plans need engine='fused' or 'stage'")
-        with get_tracer().span("stage.train", engine=engine,
-                               store=store_kind) as sp:
-            rec = _train_stage_legacy(sim, store_kind, rounds)
-            sp.annotate(stage=rec.plan.stage)
-            return rec
-    if engine == "stage" and encode_group is not None:
-        raise ValueError("encode_group is a fused-engine option; the stage "
-                         "engine always encodes all rounds in-program")
-
     fl = sim.fl
     g_rounds = rounds or fl.global_rounds
     tr = get_tracer()
-    with tr.span("stage.train", engine=engine, store=store_kind) as sp:
+    with tr.span("stage.train", store=store_kind) as sp:
         with tr.span("stage.plan") as plan_span:
             plan = sim.mgr.new_stage()
             plan_span.annotate(stage=plan.stage)
@@ -99,8 +73,7 @@ def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
                                              if c not in gone]
                     dropped.extend(cs)
                 dropped.sort()
-            store = sim._make_store(store_kind, plan,
-                                    group_rounds=encode_group or g_rounds,
+            store = sim._make_store(store_kind, plan, group_rounds=g_rounds,
                                     slice_dtype=slice_dtype,
                                     **(store_options or {}))
             if faults is not None and hasattr(store, "attach_faults"):
@@ -114,7 +87,7 @@ def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
         with tr.span("stage.data", stage=plan.stage):
             data = {s: sim._stack_client_data(cs)
                     for s, cs in plan.shard_clients.items()}
-            stacked = engine == "stage" and _stackable(plan, data)
+            stacked = _stackable(plan, data)
             if stacked:
                 shards = sorted(plan.shard_clients)
                 xs = jnp.stack([data[s][0] for s in shards])  # (S, M, n, ...)
@@ -123,21 +96,19 @@ def train_stage(sim, store_kind: str = "coded", rounds: Optional[int] = None,
         if stacked:
             return _run_stage_program(sim, plan, store, w0, xs, ys,
                                       g_rounds, kind, slice_dtype)
-        if engine == "stage":
-            sp.annotate(degraded="ragged_stage")
-            if faults is not None:
-                from repro.faults.events import DegradedModeEvent
-                faults.ledger.record(DegradedModeEvent(
-                    stage=plan.stage,
-                    reason="ragged_stage", fallback="fused",
-                    dropped_clients=tuple(dropped)))
-            else:
-                warnings.warn(
-                    "ragged stage (unequal client or sample counts per "
-                    "shard); stage engine degrading to per-shard fused "
-                    "dispatch",
-                    stacklevel=2)
-        return _run_fused(sim, plan, store, w0, data, g_rounds, kind)
+        sp.annotate(degraded="ragged_stage")
+        if faults is not None:
+            from repro.faults.events import DegradedModeEvent
+            faults.ledger.record(DegradedModeEvent(
+                stage=plan.stage,
+                reason="ragged_stage", fallback="per_shard",
+                dropped_clients=tuple(dropped)))
+        else:
+            warnings.warn(
+                "ragged stage (unequal client or sample counts per shard); "
+                "training it with one dispatch per shard and round",
+                stacklevel=2)
+        return _run_per_shard(sim, plan, store, w0, data, g_rounds, kind)
 
 
 def _stackable(plan, data) -> bool:
@@ -232,9 +203,10 @@ def _run_stage_program(sim, plan, store, w0, xs, ys, g_rounds, kind,
                        history_norms=norms, expert_load=expert_load)
 
 
-def _run_fused(sim, plan, store, w0, data, g_rounds, kind):
-    """Fused per-shard engine: one jitted ``shard_round`` per (shard, round),
-    everything stacked/device-resident (see ``repro.fl.simulator``)."""
+def _run_per_shard(sim, plan, store, w0, data, g_rounds, kind):
+    """The per-shard round, for a stage that does not stack: one jitted
+    ``shard_round`` per (shard, round), everything stacked/device-resident
+    (see ``repro.fl.simulator``), and the store's one batched encode."""
     from repro.fl.simulator import StageRecord
 
     fl = sim.fl
@@ -264,8 +236,7 @@ def _run_fused(sim, plan, store, w0, data, g_rounds, kind):
     store.flush()
     for s in shards:
         round_globals[s].append(ws[s])
-    # ONE host sync for every stored-update norm of the stage —
-    # the legacy path pulled S*G*M scalars with float(...)
+    # ONE host sync for every stored-update norm of the stage
     norms_host = jax.device_get({s: jnp.stack(norms_dev[s]) for s in shards})
     norms = {}
     for s in shards:
@@ -276,40 +247,3 @@ def _run_fused(sim, plan, store, w0, data, g_rounds, kind):
     return StageRecord(plan, dict(ws), round_globals, store,
                        history_norms=norms)
 
-
-def _train_stage_legacy(sim, store_kind: str = "coded",
-                        rounds: Optional[int] = None):
-    """Seed per-client round loop (unstack + per-scalar norm pulls +
-    per-round tree flatten/encode) — kept for A/B comparison."""
-    from repro.fl.simulator import StageRecord
-
-    fl = sim.fl
-    g_rounds = rounds or fl.global_rounds
-    plan = sim.mgr.new_stage()
-    rng = jax.random.key(sim.seed + plan.stage)
-    w0 = sim.init_params(rng)
-    store = sim._make_store(store_kind, plan)
-    ws = {s: w0 for s in plan.shard_clients}
-    data = {s: sim._stack_client_data(cs)
-            for s, cs in plan.shard_clients.items()}
-    round_globals = {s: [] for s in plan.shard_clients}
-    norms = {}
-    for g in range(g_rounds):
-        all_params = {}
-        for s, clients in plan.shard_clients.items():
-            round_globals[s].append(ws[s])
-            xs, ys = data[s]
-            locals_ = sim._local_train[fl.local_epochs](ws[s], xs, ys)
-            per_client = [jax.tree.map(lambda a, i=i: a[i], locals_)
-                          for i in range(len(clients))]
-            all_params.update(dict(zip(clients, per_client)))
-            for i, c in enumerate(clients):
-                d = unlearning.tree_sub(per_client[i], ws[s])
-                norms[(s, g, c)] = float(unlearning.tree_norm(d))
-            ws[s] = unlearning.tree_mean(per_client)
-        store.put_round(RoundPayload.from_clients(g, plan.shard_clients,
-                                                  all_params))
-    for s in plan.shard_clients:
-        round_globals[s].append(ws[s])
-    return StageRecord(plan, dict(ws), round_globals, store,
-                       history_norms=norms)

@@ -20,34 +20,20 @@ training/calibration steps, and evaluation.  Orchestration lives in
 * ``experiment.FederatedSession`` — K stages with a scheduled stream of
   unlearning requests (the paper's cross-stage isolation).
 
-``FLSimulator.train_stage`` / ``FLSimulator.unlearn`` remain as deprecated
-thin shims over those entry points.
+``FLSimulator.unlearn`` remains as a deprecated thin shim over
+``run_unlearn``.
 
-Round engines
--------------
-Three selectable engines cover the dispatch-count spectrum
-(``train_stage(..., engine=...)``; see ``repro.fl.experiment.stage``):
-
-* ``engine="stage"`` — the whole-stage superfusion: stage data is stacked to
-  ``(S, M, n, ...)``, ``shard_round`` is ``vmap``-ed over the shard axis and
-  ``lax.scan``-ed over the G rounds, so ONE jitted dispatch produces the
-  entire stage — the ``(G+1, S, ...)`` round globals, the ``(G, S, M)``
-  update norms, and (for the coded store) the coded slices themselves: the
-  ``(C, S)`` Lagrange encode matrix is applied to the ``(G, S, M*P)`` flat
-  history via einsum *inside the same XLA program*
-  (``coding.encode_rounds``), eliminating the separate encode dispatch.
-  Ragged stages (unequal clients or sample counts per shard) degrade
-  gracefully to the per-shard fused path.
-* ``engine="fused"`` — one jitted ``shard_round`` per (shard, round): vmapped
-  local training, FedAvg mean, the per-client update norms as one (M,)
-  reduction, and the stacked (M, P) flat parameter matrix
-  (``coding.tree_to_flat_stacked``) all in one program; the coded store
-  defers the Lagrange encode so G rounds batch into a single coded matmul.
-  G·S + 1 dispatches per stage.
-* ``engine="legacy"`` — the seed per-client path (unstack, per-scalar norm
-  pulls, per-round flatten+encode), kept for A/B benchmarking
-  (``benchmarks/fig6_round_engine.py``) and equivalence tests
-  (``tests/test_round_engine.py``).
+Stage training
+--------------
+One path (``repro.fl.experiment.stage``): a stage whose shard data stacks to
+``(S, M, n, ...)`` runs the stage program (``_get_stage_program``) — ONE
+jitted dispatch giving the ``(G+1, S, ...)`` round globals, the ``(G, S, M)``
+update norms and, for the coded store, the coded slices, encoded *inside the
+same XLA program* (``coding.encode_rounds``).  A ragged stage (unequal
+clients or sample counts per shard) falls back to one jitted ``shard_round``
+per (shard, round): vmapped local training, FedAvg mean, the per-client
+update norms as one (M,) reduction, and the stacked (M, P) flat parameter
+matrix (``coding.tree_to_flat_stacked``) in one program.
 
 Adapters over a frozen base
 ---------------------------
@@ -94,7 +80,7 @@ class StackedRoundGlobals:
     """List-like view of one shard's per-round global models, backed by the
     stage program's stacked ``(G, S, ...)`` output — length G+1 like the
     materialized per-shard lists, but each element is sliced out of the
-    stacked buffers only on access (the stage engine dispatches nothing for
+    stacked buffers only on access (the stage program dispatches nothing for
     bookkeeping it never reads)."""
 
     def __init__(self, round_inputs, final, shard_index: int):
@@ -126,12 +112,13 @@ class StageRecord:
     plan: StagePlan
     shard_models: Dict[int, object]               # final per-shard globals
     round_globals: Dict[int, object]              # shard -> [w^g inputs],
-    # len G+1 (a list, or a lazy StackedRoundGlobals view for engine="stage")
+    # len G+1 (a lazy StackedRoundGlobals view of the stage program's output,
+    # or a list from the per-shard round of a ragged stage)
     store: object                                 # parameter store
     history_norms: Dict[Tuple[int, int, int], float] = field(default_factory=dict)
     # (shard, round, client) -> ||delta|| of the stored update
     # tokens the stage routed to each held expert, (MoE layers, held), on
-    # device; None for a model without held experts or off the stage engine
+    # device; None for a model without held experts or for a ragged stage
     expert_load: Optional[object] = None
 
 
@@ -339,7 +326,7 @@ class FLSimulator:
     def _get_stage_program(self, epochs: int, kind: str, g_rounds: int,
                            encode: bool, out_dtype=None,
                            use_kernel: bool = False):
-        """Build (and cache) the whole-stage program for ``engine="stage"``:
+        """Build (and cache) the whole-stage program of a stage that stacks:
         ``shard_round`` vmapped over the S shards and scanned over the G
         rounds, with the coded store's Lagrange encode fused into the same
         XLA program (``coding.encode_rounds``) when ``encode``.
@@ -407,7 +394,7 @@ class FLSimulator:
 
     def _get_retrain_program(self, epochs: int, g_rounds: int):
         """Lean whole-stage program for from-scratch retraining (the
-        exact-unlearning oracle, ``repro.verify.oracle``): the stage engine's
+        exact-unlearning oracle, ``repro.verify.oracle``): the stage program's
         ``shard_round`` body vmapped over a stacked ``(K, M, n, ...)`` shard
         batch and scanned over the G rounds, returning ONLY the final
         ``(K, ...)`` models — round history, update norms, and the store
@@ -466,20 +453,6 @@ class FLSimulator:
                           **store_options)
 
     # --------------------------------------------------- deprecated shims
-    def train_stage(self, store_kind: str = "coded",
-                    rounds: Optional[int] = None, engine: str = "fused",
-                    encode_group: Optional[int] = None,
-                    slice_dtype=None) -> StageRecord:
-        """Deprecated shim over ``repro.fl.experiment.train_stage``."""
-        warnings.warn(
-            "FLSimulator.train_stage is deprecated; use "
-            "repro.fl.experiment.train_stage(sim, ...) or FederatedSession",
-            DeprecationWarning, stacklevel=2)
-        from repro.fl.experiment.stage import train_stage
-        return train_stage(self, store_kind=store_kind, rounds=rounds,
-                           engine=engine, encode_group=encode_group,
-                           slice_dtype=slice_dtype)
-
     def unlearn(self, framework: str, record: StageRecord,
                 requests: Sequence[int], rounds: Optional[int] = None,
                 available: Optional[Sequence[int]] = None,

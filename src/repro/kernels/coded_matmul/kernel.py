@@ -14,7 +14,7 @@ G-round history ``(G, S, P)`` on a 3-D ``(G, C_tiles, P_tiles)`` grid: each
 round's (S, block_p) tile streams through the MXU directly from its slot in
 the stacked history — no host-side concatenate of the rounds (the 2-D kernel
 required a (S, G*P) copy to batch rounds).  This is the encode the
-stage-program engine fuses into the training program.
+stage program fuses into the training program.
 
 ``encode_decode_kernel`` — fused code round-trip ``D @ (B @ w)``: per P-tile
 the (C, block_p) coded intermediate lives only in VMEM/registers, never HBM.

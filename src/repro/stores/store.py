@@ -17,9 +17,9 @@ point, ``put_round(RoundPayload)``.  A ``RoundPayload`` carries one round's
 parameters in whichever of three forms the producer has on hand — per-client
 trees, per-shard stacked ``(M, ...)`` trees, or per-shard pre-flattened
 ``(M, P)`` matrices — and each store consumes the richest form it supports
-(``wants`` advertises the preferred one so the round engine can compute it
+(``wants`` advertises the preferred one so the round step can compute it
 in-jit).  ``CodedStore`` additionally accepts a whole stage of slices
-already Lagrange-encoded *inside* the stage-program engine's XLA program
+already Lagrange-encoded *inside* the stage program's XLA program
 (``put_stage_encoded`` — zero store-side encode dispatches).  Stores register themselves in the ``STORES`` registry under the
 name used by ``FLSimulator``/``ScenarioConfig`` (``full`` / ``uncoded`` /
 ``coded``); third-party stores are one ``@register_store`` away.
@@ -133,10 +133,10 @@ class RoundPayload:
 
     * ``client_params`` — {client_id: pytree} (the seed per-client path).
     * ``stacked``       — {shard: (M_s, ...) pytree}, rows in
-                          ``shard_clients[shard]`` order (fused engine,
-                          uncoded stores: no per-client unstack).
-    * ``flat``          — {shard: (M_s, P) matrix} + ``row_spec`` (fused
-                          engine, coded store: flattened in-jit by
+                          ``shard_clients[shard]`` order (per-shard
+                          round, uncoded stores: no per-client unstack).
+    * ``flat``          — {shard: (M_s, P) matrix} + ``row_spec`` (per-shard
+                          round, coded store: flattened in-jit by
                           ``coding.tree_to_flat_stacked``).
 
     ``shard_clients`` always carries the round's shard membership so every
@@ -196,7 +196,7 @@ class RoundPayload:
 
 @runtime_checkable
 class ParameterStore(Protocol):
-    """The single store interface the round engine / session driver target."""
+    """The single store interface stage training and the session target."""
 
     stats: StoreStats
     wants: str        # preferred payload form: "flat" | "stacked" | "tree"
@@ -352,7 +352,7 @@ class CodedStore:
             self._put_trees(payload.rnd, payload.client_params)
         else:
             # stacked trees: flatten host-side (slow path, kept for
-            # completeness — the fused engine hands the coded store ``flat``)
+            # completeness — the per-shard round hands the coded store ``flat``)
             flat = {}
             row_spec = None
             for s, cs in sorted(payload.shard_clients.items()):
@@ -379,7 +379,7 @@ class CodedStore:
 
     def _put_flat(self, rnd: int, shard_flats: Dict[int, jnp.ndarray],
                   row_spec):
-        """Fast path for the fused round engine: per-shard *stacked, already
+        """Fast path for the per-shard round: per-shard *stacked, already
         flat* ``(M_s, P)`` client-parameter matrices (from
         ``coding.tree_to_flat_stacked`` inside the jitted round step).
 
@@ -416,15 +416,15 @@ class CodedStore:
 
     def put_stage_encoded(self, coded: jnp.ndarray, row_spec,
                           row_len: int) -> None:
-        """Whole-stage write for the stage-program engine: ``coded`` is the
+        """Whole-stage write for the stage program: ``coded`` is the
         ``(G, C, Pmax)`` slice tensor already Lagrange-encoded *inside* the
         training program (``coding.encode_rounds`` fused after the round
         scan), so the store does no encode dispatch at all — it only registers
-        per-round views and accounts bytes/FLOPs exactly like the fused
+        per-round views and accounts bytes/FLOPs exactly like the per-shard
         ``_put_flat``+``flush`` path (same shapes, same dtype).
 
         ``row_spec``/``row_len`` carry the per-client re-assembly geometry
-        (every shard must have the same client count — the stage engine's
+        (every shard must have the same client count — the stage program's
         stackability precondition, which ``train_stage`` checks before
         selecting this path).
         """

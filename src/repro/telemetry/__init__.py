@@ -4,7 +4,7 @@ The single entry point every instrumented layer uses::
 
     from repro.telemetry import get_tracer
 
-    with get_tracer().span("stage.train", stage=k, engine="stage") as sp:
+    with get_tracer().span("stage.train", stage=k, store="coded") as sp:
         ...
         sp.annotate(cost_units=cost)
 

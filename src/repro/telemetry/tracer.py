@@ -5,7 +5,7 @@ One ``Tracer`` records a forest of ``Span``s: every instrumented layer
 injection, snapshot/journal I/O) opens spans through the single
 ``get_tracer()`` entry point::
 
-    with get_tracer().span("stage.train", engine="stage", shards=2):
+    with get_tracer().span("stage.train", store="coded", shards=2):
         ...
 
 Design points, in the order they matter:

@@ -6,7 +6,7 @@ Under the paper's isolation a shard's model is a pure function of its own
 clients' data, so the exact counterfactual is computable per shard: restart
 from the stage's actual initial model (same ``plan.stage``-derived seed), run
 the stage's G rounds at the FULL L local epochs, with the requested clients'
-data simply absent.  The pass reuses the stage engine's fused ``shard_round``
+data simply absent.  The pass reuses the stage program's ``shard_round``
 body — impacted shards with matching geometry are vmapped together and the
 rounds scanned, one XLA dispatch for the whole oracle
 (``FLSimulator._get_retrain_program``).

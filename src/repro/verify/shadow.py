@@ -81,8 +81,7 @@ def train_shadow_attack(cfg, n_shadows: int = 3,
         scfg = dataclasses.replace(cfg, seed=base_seed + 7919 * (i + 1),
                                    schedule=None, num_stages=1)
         sim, test = build_simulator(scfg)
-        record = train_stage(sim, store_kind=scfg.store, rounds=rounds,
-                             engine=scfg.engine)
+        record = train_stage(sim, store_kind=scfg.store, rounds=rounds)
         iface = sim.predict_interface()
         mx = np.concatenate([sim.client_data[c][0]
                              for c in record.plan.clients])

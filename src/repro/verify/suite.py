@@ -149,8 +149,7 @@ def run_verification(cfg: ScenarioConfig,
     for probe in probes:
         probe.plant(suite)
 
-    session = FederatedSession(sim, store_kind=cfg.store, engine=cfg.engine,
-                               encode_group=cfg.encode_group,
+    session = FederatedSession(sim, store_kind=cfg.store,
                                slice_dtype=cfg.slice_dtype)
     record = session.run_stage()
     suite.record = record

@@ -317,6 +317,24 @@ class TestSessionCrashResume:
         assert info["step"] == 0 and info["start_stage"] == 1
         assert session_signature(resumed) == baseline_sig
 
+    def test_snapshot_naming_an_engine_resumes(self, tmp_path, baseline_sig):
+        """A snapshot written while sessions still named a round engine
+        carries ``"engine": "fused"``; it restores and resumes to the same
+        models."""
+        from repro.durability import session_state
+        first = FederatedSession(_tiny_sim(), store_kind="coded")
+        first.run(1, schedule=RequestSchedule(_schedule().requests[:1]))
+        state = session_state.capture_session(first)
+        assert "engine" not in state
+        state["engine"] = "fused"
+        ck = str(tmp_path / "ck")
+        CheckpointManager(ck).save(state, step=0)
+
+        resumed = FederatedSession(_tiny_sim(), store_kind="coded")
+        resumed.run(NUM_STAGES, schedule=_schedule(), resume_from=ck)
+        assert resumed.last_resume_info["start_stage"] == 1
+        assert session_signature(resumed) == baseline_sig
+
     def test_resume_from_empty_dir_raises(self, tmp_path):
         session = FederatedSession(_tiny_sim(), store_kind="coded")
         with pytest.raises(FileNotFoundError, match="no usable snapshot"):

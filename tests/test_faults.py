@@ -452,6 +452,8 @@ class TestServiceReportGuards:
 # ----------------------------------------------------- degraded training
 class TestDegradedTraining:
     def test_dropout_degrades_stage_engine_with_event(self):
+        """Dropout makes the stage ragged: training falls back to the
+        per-shard round and records it, instead of warning."""
         @register_injector("_test_drop_first_of_shard0")
         class _DropOne(FaultInjector):
             def stage_dropout(self, plan, stage, shard_clients):
@@ -459,15 +461,14 @@ class TestDegradedTraining:
                 return {s: [shard_clients[s][0]]}
 
         plan = FaultPlan(seed=FAULT_SEED).add("_test_drop_first_of_shard0")
-        sess = FederatedSession(_tiny_sim(), store_kind="coded",
-                                engine="stage", faults=plan)
+        sess = FederatedSession(_tiny_sim(), store_kind="coded", faults=plan)
         record = sess.run_stage()
         sizes = sorted(len(cs) for cs in record.plan.shard_clients.values())
         assert sizes == [3, 4]            # one client gone -> ragged stage
         degraded = [e for e in plan.ledger.events
                     if isinstance(e, DegradedModeEvent)]
         assert len(degraded) == 1
-        assert degraded[0].fallback == "fused"
+        assert degraded[0].fallback == "per_shard"
         assert degraded[0].reason == "ragged_stage"
         assert len(degraded[0].dropped_clients) == 1
         assert plan.ledger.count("client_dropout") == 1
@@ -486,7 +487,7 @@ class TestDegradedTraining:
 # --------------------------------------------------------- chaotic serves
 @pytest.fixture(scope="module")
 def trained_session():
-    sess = FederatedSession(_tiny_sim(), store_kind="coded", engine="fused")
+    sess = FederatedSession(_tiny_sim(), store_kind="coded")
     sess.run_stage()
     return sess
 

@@ -13,6 +13,7 @@ from repro.core import theory, unlearning
 from repro.core.sharding import ShardManager, adaptive_requests, even_requests
 from repro.data import client_datasets_images, make_image_data
 from repro.fl import FLSimulator
+from repro.fl.experiment import train_stage
 from repro.fl.mia import mia_f1
 
 FL_SMALL = FLConfig(num_clients=12, clients_per_round=8, num_shards=2,
@@ -38,7 +39,7 @@ def sim():
 
 @pytest.fixture(scope="module")
 def record(sim):
-    return sim.train_stage(store_kind="coded")
+    return train_stage(sim, store_kind="coded")
 
 
 def test_training_learns(sim, record):

@@ -337,7 +337,7 @@ def test_stage_gauges_how_full_the_pair_buffer_ran():
                          clients_per_round=4, num_shards=2, local_epochs=1,
                          global_rounds=2, samples_per_client=4, seq_len=16,
                          test_n=20, local_batch=2, store="coded",
-                         engine="stage", num_stages=1)
+                         num_stages=1)
     session, _ = build_session(cfg)
     model = session.sim.cfg
     held, k = model.experts_held, model.experts_per_token
@@ -359,7 +359,7 @@ def test_stage_gauges_how_full_the_pair_buffer_ran():
 
 
 def test_scenario_stage_unlearning_and_a_served_request():
-    """The tiny variant through ``run_scenario``'s path on the stage engine
+    """The tiny variant through ``run_scenario``'s path on the stage program
     and the coded store: one stage, one SE deletion, then one deletion
     request served by ``UnlearningService``."""
     from repro.fl.experiment import (RequestSchedule, ScenarioConfig,
@@ -372,7 +372,7 @@ def test_scenario_stage_unlearning_and_a_served_request():
                          clients_per_round=4, num_shards=2, local_epochs=1,
                          global_rounds=2, samples_per_client=4, seq_len=16,
                          test_n=20, local_batch=2, store="coded",
-                         engine="stage", num_stages=1, schedule=schedule)
+                         num_stages=1, schedule=schedule)
     session, (tx, ty) = build_session(cfg)
     report = session.run(cfg.num_stages, schedule=cfg.schedule)
     record = session.records[-1]

@@ -1,8 +1,8 @@
-"""Fused stacked-parameter round engine: the device-resident fast paths must
-be numerically equivalent to the seed per-client paths they replace —
-stacked flatten vs per-tree flatten, batched vs per-round encode, fused
-shard_round vs the legacy loop (bit-for-bit), stacked vs sequential
-calibration, and the bf16 / grouped-encode store options."""
+"""Stage training's device-resident paths must be numerically equivalent to
+the per-client references they replace — stacked flatten vs per-tree
+flatten, batched vs per-round encode, the one-dispatch stage program vs the
+per-shard round of a ragged stage, stacked vs sequential calibration, and
+the bf16 / grouped-encode store options."""
 import dataclasses
 
 import jax
@@ -17,6 +17,7 @@ from repro.data import client_datasets_images, make_image_data
 from repro.fl import FLSimulator
 from repro.fl.experiment import (ScenarioConfig, build_simulator, run_unlearn,
                                  train_stage)
+from repro.fl.experiment.stage import _run_per_shard
 
 
 def _stacked_tree(m=5, seed=0):
@@ -174,7 +175,7 @@ class TestCalibrateStacked:
                                        rtol=1e-5, atol=1e-5)
 
 
-# --------------------------------------------------- end-to-end round engine
+# ------------------------------------------------- end-to-end stage training
 FL_TINY = FLConfig(num_clients=8, clients_per_round=8, num_shards=2,
                    local_epochs=2, global_rounds=3, retrain_ratio=2.0)
 
@@ -190,105 +191,65 @@ def _tiny_sim():
                        local_batch=10)
 
 
-class TestFusedEngineEquivalence:
-    @pytest.fixture(scope="class")
-    def records(self):
-        s_leg, s_fus = _tiny_sim(), _tiny_sim()
-        return (train_stage(s_leg, store_kind="coded", engine="legacy"),
-                train_stage(s_fus, store_kind="coded", engine="fused"), s_fus)
-
-    def test_shard_models_bit_for_bit(self, records):
-        r_leg, r_fus, _ = records
-        assert r_leg.plan.shard_clients == r_fus.plan.shard_clients
-        for s in r_leg.shard_models:
-            for a, b in zip(jax.tree.leaves(r_leg.shard_models[s]),
-                            jax.tree.leaves(r_fus.shard_models[s])):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    def test_coded_slices_bit_for_bit(self, records):
-        r_leg, r_fus, _ = records
-        assert set(r_leg.store._slices) == set(r_fus.store._slices)
-        for g, sl in r_leg.store._slices.items():
-            np.testing.assert_array_equal(np.asarray(sl),
-                                          np.asarray(r_fus.store._slices[g]))
-
-    def test_history_norms_match(self, records):
-        r_leg, r_fus, _ = records
-        assert set(r_leg.history_norms) == set(r_fus.history_norms)
-        for k, v in r_leg.history_norms.items():
-            # one-array fetch vs per-scalar pulls: identical up to reduce
-            # layout (observed <= 1 ulp)
-            assert abs(v - r_fus.history_norms[k]) <= 1e-5 * max(abs(v), 1.0)
-
-    def test_stored_round_reconstruction_matches(self, records):
-        r_leg, r_fus, _ = records
-        for s in r_leg.plan.shard_clients:
-            g_leg = r_leg.store.get_shard(0, s)
-            g_fus = r_fus.store.get_shard(0, s)
-            assert set(g_leg) == set(g_fus)
-            for c in g_leg:
-                for a, b in zip(jax.tree.leaves(g_leg[c]),
-                                jax.tree.leaves(g_fus[c])):
-                    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                               rtol=1e-5, atol=1e-5)
-
-    def test_unlearning_runs_on_fused_record(self, records):
-        _, r_fus, sim = records
-        victim = r_fus.plan.shard_clients[0][0]
-        for fw in ("SE", "FE", "FR", "RR"):
-            res = run_unlearn(sim, fw, r_fus, [victim], rounds=2)
-            leaves = jax.tree.leaves(list(res.models.values())[0])
-            assert all(np.all(np.isfinite(np.asarray(l, np.float32)))
-                       for l in leaves), fw
+def _per_shard_stage(sim, store_kind="coded"):
+    """``train_stage``'s set-up, then the per-shard round directly — on a
+    stage that would stack, the reference for the stage program."""
+    plan = sim.mgr.new_stage()
+    w0 = sim.init_params(jax.random.key(sim.seed + plan.stage))
+    g_rounds = sim.fl.global_rounds
+    store = sim._make_store(store_kind, plan, group_rounds=g_rounds)
+    kind = "flat" if store.wants == "flat" else "stacked"
+    data = {s: sim._stack_client_data(cs)
+            for s, cs in plan.shard_clients.items()}
+    return _run_per_shard(sim, plan, store, w0, data, g_rounds, kind)
 
 
-class TestStageEngineEquivalence:
-    """The whole-stage superfusion (engine='stage': scan-over-rounds x
-    vmap-over-shards + in-program Lagrange encode) must reproduce the fused
-    per-shard engine: shard models, stored coded slices, history norms, store
-    accounting — and its lazy round-globals view must behave like the
-    materialized lists."""
+class TestStageProgramVsPerShard:
+    """The stage program (scan-over-rounds x vmap-over-shards + in-program
+    Lagrange encode) must reproduce the per-shard round of a ragged stage on
+    a stage that stacks: shard models, stored coded slices, history norms,
+    store accounting, unlearning — and its lazy round-globals view must
+    behave like the materialized lists."""
 
     @pytest.fixture(scope="class")
     def records(self):
-        s_fus, s_stg = _tiny_sim(), _tiny_sim()
-        return (train_stage(s_fus, store_kind="coded", engine="fused"),
-                train_stage(s_stg, store_kind="coded", engine="stage"), s_stg)
+        s_per, s_stg = _tiny_sim(), _tiny_sim()
+        return (_per_shard_stage(s_per), train_stage(s_stg), s_stg, s_per)
 
     def test_shard_models_bit_for_bit(self, records):
-        r_fus, r_stg, _ = records
-        assert r_fus.plan.shard_clients == r_stg.plan.shard_clients
-        for s in r_fus.shard_models:
-            for a, b in zip(jax.tree.leaves(r_fus.shard_models[s]),
+        r_per, r_stg, *_ = records
+        assert r_per.plan.shard_clients == r_stg.plan.shard_clients
+        for s in r_per.shard_models:
+            for a, b in zip(jax.tree.leaves(r_per.shard_models[s]),
                             jax.tree.leaves(r_stg.shard_models[s])):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_coded_slices_match(self, records):
         """In-program einsum encode vs the store's batched matmul encode —
-        bit-identical on CPU; the acceptance bound for the fused-encode path
+        bit-identical on CPU; the acceptance bound for the in-program encode
         is <=1e-5 rel."""
-        r_fus, r_stg, _ = records
-        assert set(r_fus.store._slices) == set(r_stg.store._slices)
-        for g, sl in r_fus.store._slices.items():
+        r_per, r_stg, *_ = records
+        assert set(r_per.store._slices) == set(r_stg.store._slices)
+        for g, sl in r_per.store._slices.items():
             np.testing.assert_allclose(np.asarray(sl),
                                        np.asarray(r_stg.store._slices[g]),
                                        rtol=1e-5, atol=1e-6)
 
     def test_history_norms_match(self, records):
-        r_fus, r_stg, _ = records
-        assert set(r_fus.history_norms) == set(r_stg.history_norms)
-        for k, v in r_fus.history_norms.items():
+        r_per, r_stg, *_ = records
+        assert set(r_per.history_norms) == set(r_stg.history_norms)
+        for k, v in r_per.history_norms.items():
             assert abs(v - r_stg.history_norms[k]) <= 1e-5 * max(abs(v), 1.0)
 
     def test_store_accounting_matches(self, records):
-        r_fus, r_stg, _ = records
-        assert r_fus.store.stats == r_stg.store.stats
+        r_per, r_stg, *_ = records
+        assert r_per.store.stats == r_stg.store.stats
 
     def test_round_globals_lazy_view(self, records):
-        r_fus, r_stg, _ = records
+        r_per, r_stg, *_ = records
         for s in r_stg.plan.shard_clients:
             view = r_stg.round_globals[s]
-            ref = r_fus.round_globals[s]
+            ref = r_per.round_globals[s]
             assert len(view) == len(ref) == FL_TINY.global_rounds + 1
             for g in (0, len(ref) - 1, -1):
                 for a, b in zip(jax.tree.leaves(ref[g]),
@@ -297,19 +258,19 @@ class TestStageEngineEquivalence:
                                                   np.asarray(b))
 
     def test_stored_round_reconstruction_matches(self, records):
-        r_fus, r_stg, _ = records
-        for s in r_fus.plan.shard_clients:
-            g_fus = r_fus.store.get_shard(0, s)
+        r_per, r_stg, *_ = records
+        for s in r_per.plan.shard_clients:
+            g_per = r_per.store.get_shard(0, s)
             g_stg = r_stg.store.get_shard(0, s)
-            assert set(g_fus) == set(g_stg)
-            for c in g_fus:
-                for a, b in zip(jax.tree.leaves(g_fus[c]),
+            assert set(g_per) == set(g_stg)
+            for c in g_per:
+                for a, b in zip(jax.tree.leaves(g_per[c]),
                                 jax.tree.leaves(g_stg[c])):
                     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                                rtol=1e-5, atol=1e-5)
 
     def test_unlearning_runs_on_stage_record(self, records):
-        _, r_stg, sim = records
+        _, r_stg, sim, _ = records
         victim = r_stg.plan.shard_clients[0][0]
         for fw in ("SE", "FE", "FR", "RR"):
             res = run_unlearn(sim, fw, r_stg, [victim], rounds=2)
@@ -317,24 +278,43 @@ class TestStageEngineEquivalence:
             assert all(np.all(np.isfinite(np.asarray(l, np.float32)))
                        for l in leaves), fw
 
-    def test_uncoded_store_stage_engine(self):
-        s_stg, s_fus = _tiny_sim(), _tiny_sim()
-        r_stg = train_stage(s_stg, store_kind="uncoded", engine="stage")
-        r_fus = train_stage(s_fus, store_kind="uncoded", engine="fused")
-        assert r_stg.store.stats.server_bytes == r_fus.store.stats.server_bytes
+    @pytest.mark.parametrize("fw", ["SE", "FE", "FR", "RR"])
+    def test_unlearning_cannot_tell_paths_apart(self, records, fw):
+        """Each framework unlearns a stage-program record as it does the
+        per-shard record of the same stage: same models, same cost."""
+        r_per, r_stg, s_stg, s_per = records
+        victim = r_stg.plan.shard_clients[0][0]
+        res_stg = run_unlearn(s_stg, fw, r_stg, [victim], rounds=2)
+        res_per = run_unlearn(s_per, fw, r_per, [victim], rounds=2)
+        assert res_stg.impacted_shards == res_per.impacted_shards
+        assert res_stg.cost_units == res_per.cost_units
+        assert set(res_stg.models) == set(res_per.models)
+        for s in res_per.models:
+            for a, b in zip(jax.tree.leaves(res_per.models[s]),
+                            jax.tree.leaves(res_stg.models[s])):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("store_kind",
+                             ["full", "uncoded", "coded", "tiered"])
+    def test_store_matches_per_shard(self, store_kind):
+        s_stg, s_per = _tiny_sim(), _tiny_sim()
+        r_stg = train_stage(s_stg, store_kind=store_kind)
+        r_per = _per_shard_stage(s_per, store_kind)
+        assert r_stg.store.stats == r_per.store.stats
         c = r_stg.plan.shard_clients[0][0]
         for a, b in zip(jax.tree.leaves(r_stg.store.get(0, c)),
-                        jax.tree.leaves(r_fus.store.get(0, c))):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    def test_encode_group_rejected(self):
-        with pytest.raises(ValueError, match="fused-engine option"):
-            train_stage(_tiny_sim(), engine="stage", encode_group=2)
+                        jax.tree.leaves(r_per.store.get(0, c))):
+            if store_kind in ("full", "uncoded"):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            else:       # decoded from coded slices
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-5, atol=1e-5)
 
     def test_ragged_stage_falls_back(self):
         """Unequal per-client sample counts across shards break the (S, M, n)
-        stack — the stage engine must warn and degrade to the per-shard fused
-        path, producing the same record the fused engine would."""
+        stack — training must warn and fall back to the per-shard round,
+        producing the same record as calling that round directly."""
         cfg = dataclasses.replace(get_config("cnn-paper"), image_size=8,
                                   d_model=16, cnn_channels=(4, 4))
         data = make_image_data(8 * 30, image_size=8, seed=0)
@@ -349,13 +329,13 @@ class TestStageEngineEquivalence:
                                                        grad_clip=0.0),
                                local_batch=10)
 
-        s_stg, s_fus = mk(), mk()
+        s_stg, s_per = mk(), mk()
         with pytest.warns(UserWarning, match="ragged stage"):
-            r_stg = train_stage(s_stg, store_kind="coded", engine="stage")
-        r_fus = train_stage(s_fus, store_kind="coded", engine="fused")
-        assert r_stg.plan.shard_clients == r_fus.plan.shard_clients
-        for s in r_fus.shard_models:
-            for a, b in zip(jax.tree.leaves(r_fus.shard_models[s]),
+            r_stg = train_stage(s_stg, store_kind="coded")
+        r_per = _per_shard_stage(s_per)
+        assert r_stg.plan.shard_clients == r_per.plan.shard_clients
+        for s in r_per.shard_models:
+            for a, b in zip(jax.tree.leaves(r_per.shard_models[s]),
                             jax.tree.leaves(r_stg.shard_models[s])):
                 np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -368,7 +348,7 @@ class TestBatchedCalibration:
         from repro.fl.experiment.frameworks import (ShardedEraser,
                                                     UnlearnContext)
         sim = _tiny_sim()
-        rec = train_stage(sim, store_kind="coded", engine="stage")
+        rec = train_stage(sim, store_kind="coded")
         victims = [rec.plan.shard_clients[0][0], rec.plan.shard_clients[1][0]]
         fw = ShardedEraser()
         ctx = UnlearnContext(sim, rec, victims, FL_TINY.global_rounds)
@@ -388,7 +368,7 @@ class TestBatchedCalibration:
         from repro.fl.experiment.frameworks import (ShardedEraser,
                                                     UnlearnContext)
         sim = _tiny_sim()
-        rec = train_stage(sim, store_kind="coded", engine="fused")
+        rec = train_stage(sim, store_kind="coded")
         # two victims in shard 0, one in shard 1: retained counts differ
         victims = rec.plan.shard_clients[0][:2] + [rec.plan.shard_clients[1][0]]
         fw = ShardedEraser()
@@ -423,29 +403,15 @@ class TestVmappedEvaluate:
 
 
 class TestDeprecatedShims:
-    """train_stage/unlearn stay callable on the simulator as thin wrappers
-    over the experiment API: they warn, and their results are bit-identical
-    to the new path on identically-seeded sims."""
+    """``FLSimulator.unlearn`` stays callable as a thin wrapper over
+    ``run_unlearn``: it warns, and its results are bit-identical to the new
+    path on identically-seeded sims."""
 
     @pytest.fixture(scope="class")
     def pair(self):
         s_new, s_old = _tiny_sim(), _tiny_sim()
-        r_new = train_stage(s_new, store_kind="coded")
-        with pytest.warns(DeprecationWarning, match="train_stage is deprecated"):
-            r_old = s_old.train_stage(store_kind="coded")
-        return s_new, r_new, s_old, r_old
-
-    def test_train_stage_shim_equivalent(self, pair):
-        _, r_new, _, r_old = pair
-        assert r_old.plan.shard_clients == r_new.plan.shard_clients
-        for s in r_new.shard_models:
-            for a, b in zip(jax.tree.leaves(r_old.shard_models[s]),
-                            jax.tree.leaves(r_new.shard_models[s])):
-                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        assert r_old.history_norms == r_new.history_norms
-        for g, sl in r_new.store._slices.items():
-            np.testing.assert_array_equal(np.asarray(r_old.store._slices[g]),
-                                          np.asarray(sl))
+        return (s_new, train_stage(s_new, store_kind="coded"),
+                s_old, train_stage(s_old, store_kind="coded"))
 
     @pytest.mark.parametrize("fw", ["SE", "FE", "FR", "RR"])
     def test_unlearn_shim_equivalent(self, pair, fw):

@@ -147,10 +147,6 @@ class TestScenarioValidation:
         with pytest.raises(ValueError, match="registered:.*coded"):
             ScenarioConfig(store="codedx")
 
-    def test_unknown_engine(self):
-        with pytest.raises(ValueError, match="stage.*fused.*legacy"):
-            ScenarioConfig(engine="turbo")
-
     def test_unknown_scheduled_framework(self):
         sched = RequestSchedule([UnlearnRequest([0], framework="SEE")])
         with pytest.raises(ValueError, match="registered:.*SE"):

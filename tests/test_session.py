@@ -131,7 +131,7 @@ class TestMultiStageSession:
 
     @pytest.fixture(scope="class")
     def scheduled(self):
-        """Shim path (per-stage train_stage/unlearn) vs FederatedSession on
+        """Per-stage train_stage + the unlearn shim vs FederatedSession on
         identically-seeded sims, with an SE request interleaved after every
         stage."""
         sim_a, sim_b = _tiny_sim(), _tiny_sim()
@@ -139,8 +139,7 @@ class TestMultiStageSession:
         # --- reference: the single-stage API, stage by stage --------------
         records_a, unlearns_a, victims = [], [], []
         for k in range(self.N_STAGES):
-            with pytest.warns(DeprecationWarning):
-                rec = sim_a.train_stage(store_kind="coded")
+            rec = train_stage(sim_a, store_kind="coded")
             records_a.append(rec)
             victim = rec.plan.shard_clients[k % rec.plan.num_shards][0]
             victims.append(victim)
@@ -278,6 +277,16 @@ class TestSessionSemantics:
         with pytest.raises(ValueError, match="stage"):
             session.unlearn(UnlearnRequest([victim], stages=[5]))
 
+    @pytest.mark.parametrize("engine", ["stage", "fused", "legacy", "turbo"])
+    def test_engine_accepts_only_stage(self, engine):
+        """Stage training has one path; ``engine`` may name only it."""
+        if engine == "stage":
+            s = FederatedSession(_tiny_sim(), engine=engine)
+            assert s.run_stage() is s.records[0]
+        else:
+            with pytest.raises(ValueError, match="engine='stage'"):
+                FederatedSession(_tiny_sim(), engine=engine)
+
     def test_stage_report_uses_session_local_index(self):
         """A session on a simulator that already trained stages still
         reports/routes by session-local index."""
@@ -355,7 +364,7 @@ class TestBatchedRequests:
         cfg = ScenarioConfig(num_clients=8, clients_per_round=8, num_shards=2,
                              local_epochs=2, global_rounds=2,
                              samples_per_client=30, image_size=8, test_n=50,
-                             engine="stage", batch_requests=True,
+                             batch_requests=True,
                              schedule=RequestSchedule([
                                  UnlearnRequest(
                                      lambda p: [p.shard_clients[0][0]],

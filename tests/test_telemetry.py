@@ -275,11 +275,11 @@ class TestExport:
 
 # --------------------------------------------------- integration (jit-heavy)
 def _traced_service_run():
-    """One seeded, fully traced workload: two stage-engine training stages,
+    """One seeded, fully traced workload: two stage-program training stages,
     then a window-policy serve of three SE requests on one device."""
     tr = configure(enabled=True)
     sim = _tiny_sim(seed=0)
-    session = FederatedSession(sim, store_kind="coded", engine="stage")
+    session = FederatedSession(sim, store_kind="coded")
     session.run_stage()
     session.run_stage()
     svc = UnlearningService(session, policy="window",
@@ -324,7 +324,7 @@ class TestIntegration:
     def test_session_audit_chain_spans_batched_unlearning(self, tmp_path):
         configure(enabled=True)
         session = FederatedSession(_tiny_sim(seed=0), store_kind="coded",
-                                   engine="stage", batch_requests=True,
+                                   batch_requests=True,
                                    checkpoint_every=1,
                                    checkpoint_dir=str(tmp_path))
         schedule = RequestSchedule([
@@ -371,19 +371,19 @@ class TestIntegration:
         n = 100_000
         t0 = time.perf_counter()
         for _ in range(n):
-            with tr.span("stage.train", engine="stage", shards=2) as sp:
+            with tr.span("stage.train", store="coded", shards=2) as sp:
                 sp.annotate(stage=1)
         per_call = (time.perf_counter() - t0) / n
 
         # count the instrumentation sites one traced stage actually hits,
         # and the wall of the same stage untraced (warm jit)
         sim = _tiny_sim(seed=0)
-        train_stage(sim, store_kind="coded", engine="stage")   # warm
+        train_stage(sim, store_kind="coded")   # warm
         t0 = time.perf_counter()
-        train_stage(sim, store_kind="coded", engine="stage")
+        train_stage(sim, store_kind="coded")
         stage_wall = time.perf_counter() - t0
         configure(enabled=True)
-        train_stage(sim, store_kind="coded", engine="stage")
+        train_stage(sim, store_kind="coded")
         n_sites = len(get_tracer().all_spans())
         set_tracer(NULL_TRACER)
 
@@ -432,7 +432,7 @@ class TestProfilerTrace:
     @pytest.mark.parametrize("enabled", [True, False])
     def test_stage_spans_in_profiler_trace(self, enabled):
         sim = _tiny_sim(seed=0)
-        session = FederatedSession(sim, store_kind="coded", engine="stage")
+        session = FederatedSession(sim, store_kind="coded")
         session.run_stage()                                  # compile first
         configure(enabled=enabled)
 

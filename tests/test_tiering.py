@@ -206,7 +206,6 @@ def _schedule(rounds=1):
 
 def _run_session(store, store_options=None, seed=3):
     session = FederatedSession(_tiny_sim(seed), store_kind=store,
-                               engine="stage",
                                store_options=store_options or {})
     report = session.run(1, schedule=_schedule())
     return session, report

@@ -13,7 +13,8 @@ metadata aside, is the one it has been since the simulator learnt to train
 adapters over a frozen base.  Two more compile Moonlight's held-expert
 layer (vmapped over shards and clients, with its input gradient) and the
 tiny variant's stage program, and read in them that the held experts run
-as grouped matmuls over the routed pairs and take no weight gradient.
+as grouped matmuls over the routed pairs and take no weight gradient; the
+latter program, metadata aside, is pinned like nanogpt's.
 
 The topology is described inside a module-scoped fixture, never while the
 module is imported: only one process at a time may load the TPU library.
@@ -212,18 +213,14 @@ def test_held_experts_run_as_grouped_matmuls(one_chip, monkeypatch):
         (held, d, f), (held, f, d), (held, d, 2 * f)}
 
 
-def test_moonlight_stage_program_trains_no_expert_weight(one_chip,
-                                                          monkeypatch):
-    """The tiny variant's stage program: in each MoE layer two grouped
-    matmuls run forward and three backward (the rematerialised forward keeps
-    only the gate and up projections), all over pair buffers and under
-    ``moe.experts``; none has a weight's shape, so none is a gradient for the
-    frozen experts."""
+@pytest.fixture(scope="module")
+def moonlight_stage_text(one_chip):
+    """The tiny Moonlight variant's stage program, compiled with the held
+    experts steered onto their TPU path (grouped matmuls)."""
     from repro.configs import FLConfig, OptimizerConfig
     from repro.fl.families import get_model_family
     from repro.fl.simulator import FLSimulator
     from repro.models import moe
-    monkeypatch.setattr(moe, "on_tpu", lambda: True)
     cfg = get_model_family("moonlight").build(None)
     fl = FLConfig(num_clients=8, clients_per_round=4, num_shards=2,
                   local_epochs=1, global_rounds=2)
@@ -234,12 +231,47 @@ def test_moonlight_stage_program_trains_no_expert_weight(one_chip,
     shape = lambda a: _sds(a.shape, one_chip, a.dtype)  # noqa: E731
     xs = _sds((2, 2, 4, 6), one_chip, jnp.int32)
     prog = sim._get_stage_program(1, "flat", 2, encode=True)
-    text = prog.func.lower(
-        jax.tree.map(shape, sim.base),
-        jax.tree.map(shape, sim.init_params(jax.random.key(5))),
-        xs, xs, _sds((4, 2), one_chip)).compile().as_text()
+    # lowered without source frames: the kernels' serialized bodies would
+    # otherwise carry file paths and line numbers, out of strip_metadata's
+    # reach, and the pin below would follow the checkout and every edit
+    frames = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "on_tpu", lambda: True)
+            return cfg, prog.func.lower(
+                jax.tree.map(shape, sim.base),
+                jax.tree.map(shape, sim.init_params(jax.random.key(5))),
+                xs, xs, _sds((4, 2), one_chip)).compile().as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", frames)
+
+
+def test_moonlight_stage_program_trains_no_expert_weight(moonlight_stage_text):
+    """The tiny variant's stage program: in each MoE layer two grouped
+    matmuls run forward and three backward (the rematerialised forward keeps
+    only the gate and up projections), all over pair buffers and under
+    ``moe.experts``; none has a weight's shape, so none is a gradient for the
+    frozen experts."""
+    from repro.models import moe
+    cfg, text = moonlight_stage_text
     kernels = _grouped_matmuls(text)
     assert sorted(dims for dims, _ in kernels) == _expected(
         moe.pair_buffer_sizes(2 * 2 * 2 * 6, cfg.experts_per_token,
                               cfg.experts_held), cfg.d_model, cfg.moe_d_ff)
     assert all("moe.experts" in name for _, name in kernels)
+
+
+# sha256 of that program's text with its metadata stripped, as it compiled
+# when stage training still had three round engines to choose from (taken
+# on that code, lowered as above): with one training path, the adapter
+# path's measured program must be the very same.
+MOONLIGHT_STAGE_SHA256 = \
+    "d8444ba800601531067a7019d143887a58445556077f4724926c6e376c043cf6"
+
+
+def test_moonlight_stage_program_is_unchanged(moonlight_stage_text):
+    stripped = strip_metadata(moonlight_stage_text[1])
+    assert "op_name" not in stripped and "StackFrames" not in stripped
+    assert hashlib.sha256(stripped.encode()).hexdigest() == \
+        MOONLIGHT_STAGE_SHA256

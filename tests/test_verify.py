@@ -40,7 +40,7 @@ from repro.verify.report import CandidateScore, VerifyReport
 CFG = ScenarioConfig(task="classification", num_clients=8, clients_per_round=8,
                      num_shards=2, samples_per_client=32, image_size=10,
                      local_epochs=8, global_rounds=6, test_n=160, seed=3,
-                     lr=0.3, noise=0.35, store="coded", engine="fused")
+                     lr=0.3, noise=0.35, store="coded")
 N_SHADOWS = 2
 N_CANARIES = 12
 
@@ -128,7 +128,7 @@ def test_bit_reproducible_under_fixed_seed(report, repeat_report):
 def test_oracle_matches_manual_retrain_loop():
     cfg = dataclasses.replace(CFG, local_epochs=3, global_rounds=3, test_n=80)
     sim, _ = build_simulator(cfg)
-    record = train_stage(sim, store_kind=cfg.store, engine=cfg.engine)
+    record = train_stage(sim, store_kind=cfg.store)
     victim = record.plan.clients[0]
     res = run_unlearn(sim, "oracle", record, [victim])
 
@@ -268,7 +268,7 @@ def test_mia_features_accept_spec_and_aliases():
 def test_predict_interface_public_surface():
     cfg = dataclasses.replace(CFG, local_epochs=1, global_rounds=1, test_n=40)
     sim, test = build_simulator(cfg)
-    record = train_stage(sim, store_kind=cfg.store, engine=cfg.engine)
+    record = train_stage(sim, store_kind=cfg.store)
     iface = sim.predict_interface()
     assert iface.task is sim.task_spec
     x, y = test[0][:8], test[1][:8]
@@ -284,7 +284,7 @@ def test_predict_stage_victim_matches_trained_plan():
     cfg = dataclasses.replace(CFG, local_epochs=1, global_rounds=1, test_n=40)
     victim = predict_stage_victim(cfg)
     sim, _ = build_simulator(cfg)
-    record = train_stage(sim, store_kind=cfg.store, engine=cfg.engine)
+    record = train_stage(sim, store_kind=cfg.store)
     assert victim in record.plan.clients
 
 
